@@ -42,6 +42,8 @@ def main() -> None:
     if bad:
         ap.error(f"unknown section(s) {bad}; choose from {SECTIONS}")
     wanted = args.sections or SECTIONS
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     for name in wanted:
         mod = __import__(f"benchmarks.bench_{name}"
                          if name != "roofline" else "benchmarks.roofline_table",

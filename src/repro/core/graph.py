@@ -68,11 +68,14 @@ live) and reads ``ctx.resharder``.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Optional, Sequence
+
+import jax
 
 from repro.core.resharding import ReshardLedger
 from repro.obs import MetricsRegistry, get_tracer
@@ -256,6 +259,7 @@ class GraphExecutor:
         self.faults = faults              # FaultPlan | None (chaos hook)
         self.retry = retry if retry is not None else RetryPolicy()
         self.metrics = metrics if metrics is not None else MetricsRegistry()
+        self._mesh_stage = threading.Lock()   # see _mesh_scope
 
     # -- thread-safe dock access -------------------------------------------
     def put(self, node: StageNode, fld: str, idxs, rows) -> None:
@@ -326,6 +330,22 @@ class GraphExecutor:
             self._stash = None
         self._layout = want
 
+    @contextlib.contextmanager
+    def _mesh_scope(self, ctx):
+        """A stage's device context when the trainer's mesh spans several
+        devices: ``jax.set_mesh`` (thread-local, so per stage thread) lets
+        ``kernels.ops`` run each Pallas kernel per shard, since XLA cannot
+        partition one.  Such stages run one at a time: two threads launching
+        programs with collectives on the same devices may enqueue them in a
+        different order on each device and deadlock.  On one device stages
+        keep their concurrency and their programs stay unchanged."""
+        mesh = getattr(getattr(ctx, "resharder", None), "mesh", None)
+        if mesh is None or mesh.size == 1:
+            yield
+            return
+        with self._mesh_stage, jax.set_mesh(mesh):
+            yield
+
     # -- dispatch -----------------------------------------------------------
     def _dispatch(self, node: StageNode, idxs, ctx, *, round_: int = 0,
                   fused: bool = False, stream: bool = False) -> None:
@@ -373,7 +393,8 @@ class GraphExecutor:
     def _attempt_stage(self, node: StageNode, idxs, ctx) -> StageIO:
         ins = self._fetch(node, idxs)
         io = StageIO(node, idxs, ins, self)
-        out = node.fn(ctx, io)
+        with self._mesh_scope(ctx):
+            out = node.fn(ctx, io)
         if out:
             for fld, rows in out.items():
                 self.put(node, fld, io.idxs, rows)

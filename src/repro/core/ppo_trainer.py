@@ -19,9 +19,7 @@ import numpy as np
 from repro.configs.base import ModelConfig, RLConfig
 from repro.core import ppo
 from repro.core.graph import RLGraph, derive_nodes
-from repro.core.resharding import Resharder
 from repro.core.trainer import GRPOTrainer, build_grpo_graph
-from repro.optim import adamw_init
 
 
 def build_ppo_graph(actor_node: int = 0, ref_node: int = 1,
@@ -54,18 +52,12 @@ class PPOTrainer(GRPOTrainer):
         self.pf = pf_filter
         super().__init__(cfg, rl, dataset, **kw)
         key = jax.random.PRNGKey(kw.get("seed", 0) + 17)
-        self.params = ppo.add_value_head(self.params, cfg, key)
-        self.opt_state = adamw_init(self.params)
+        # the optimizer state and the resharder must carry the value head
+        self._init_state(lambda p, k: ppo.add_value_head(p, cfg, k),
+                         self.params, key)
         self.train_step = jax.jit(ppo.make_train_step(cfg, rl),
                                   donate_argnums=(0, 1))
         self._values = jax.jit(self._values_impl)
-        # the resharder must carry the value head too
-        from repro.sharding import param_specs
-        tspecs = param_specs(cfg, self.params, self.mesh, stage="train")
-        gspecs = param_specs(cfg, self.params, self.mesh, stage="gen",
-                             gen_mode="tp")
-        self.resharder = Resharder(self.mesh, tspecs, gspecs,
-                                   use_swap=rl.use_allgather_swap)
 
     def _build_graph(self) -> RLGraph:
         return build_ppo_graph(self.actor.node, self.ref.node,

@@ -13,8 +13,8 @@ Implements the paper's two strategies:
     for the KV cache), (4) free the temp buffer.  Before the next update the
     weights are swapped H2D (overlappable with the inference stage).
 
-On TPU the D2H/H2D path is the native ``memory_kind="pinned_host"``; the CPU
-container exposes the same memory kinds, so the identical code runs here.
+The D2H/H2D path is the native ``memory_kind="pinned_host"``, which both the
+TPU and the CPU backends expose, so the identical code runs everywhere.
 Every step is recorded in a ``ReshardLedger`` (per-device bytes + modeled
 durations with the paper's 50 GB/s H2D bandwidth), which benchmarks use to
 reproduce Figure 10.
@@ -137,20 +137,12 @@ class Resharder:
             is_leaf=lambda x: isinstance(x, P))
         self.use_swap = use_swap
         self.paper_two_step = paper_two_step
-        self._supports_host = self._detect_host_memory()
-
-    def _detect_host_memory(self) -> bool:
-        try:
-            kinds = [m.kind for m in jax.devices()[0].addressable_memories()]
-            return "pinned_host" in kinds
-        except Exception:
-            return False
 
     # -- generation direction -------------------------------------------------
     def to_generation(self, params):
         """Returns (gen_params, stash, ledger).  ``stash`` holds the update
-        weights off the device (host memory kind, or numpy fallback) and is
-        consumed by ``to_update``."""
+        weights off the device (``pinned_host`` memory) and is consumed by
+        ``to_update``."""
         led = ReshardLedger()
         t0 = time.perf_counter()
         mesh = self.mesh
@@ -180,13 +172,9 @@ class Resharder:
             led.log("generation layout materialized", gb)
 
         if self.use_swap:
-            if self._supports_host:
-                host = jax.tree.map(
-                    lambda l, sh: jax.device_put(l, _host_sharding(sh)),
-                    params, self.train_shardings)
-            else:
-                host = jax.tree.map(lambda l: np.asarray(jax.device_get(l)),
-                                    params)
+            host = jax.tree.map(
+                lambda l, sh: jax.device_put(l, _host_sharding(sh)),
+                params, self.train_shardings)
             led.d2h_bytes = tree_device_bytes(params, self.train_specs, mesh)
             jax.block_until_ready(jax.tree.leaves(gen))
             led.log("update weights swapped D2H", -upd_dev)

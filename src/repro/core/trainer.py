@@ -20,6 +20,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, RLConfig
 from repro.core import grpo
@@ -35,6 +36,7 @@ from repro.launch.mesh import make_local_mesh
 from repro.models.model import build_model
 from repro.obs import Tracer, get_tracer
 from repro.optim import adamw_init
+from repro.optim.adamw import AdamWState
 from repro.sharding import param_specs
 
 
@@ -122,25 +124,17 @@ class GRPOTrainer:
         self.faults = faults     # FaultPlan | None — chaos hooks everywhere
         self._iters_run = 0
 
-        # --- model / optimizer state -----------------------------------
+        # --- model / optimizer state, in the update layout ---------------
         model = build_model(cfg)
         self.key, k = jax.random.split(self.key)
-        self.params = model.init(cfg, k)
+        self.mesh = mesh or make_local_mesh()
+        self._init_state(lambda k: model.init(cfg, k), k)
         # genuine copy: train_step donates self.params' buffers, so the
         # frozen reference policy must own distinct ones
         self.ref_params = jax.tree.map(jnp.copy, self.params)
-        self.opt_state = adamw_init(self.params)
         self.train_step = jax.jit(grpo.make_train_step(cfg, rl),
                                   donate_argnums=(0, 1))
         self.gen_params = None   # generation-layout weights (executor-owned)
-
-        # --- distribution -----------------------------------------------
-        self.mesh = mesh or make_local_mesh()
-        tspecs = param_specs(cfg, self.params, self.mesh, stage="train")
-        gspecs = param_specs(cfg, self.params, self.mesh, stage="gen",
-                             gen_mode="tp")
-        self.resharder = Resharder(self.mesh, tspecs, gspecs,
-                                   use_swap=rl.use_allgather_swap)
 
         # --- workers + graph + dock --------------------------------------
         self.actor = ActorWorker(cfg, rl, eos_id=self.tok.eos_id,
@@ -162,6 +156,23 @@ class GRPOTrainer:
         self.executor = GraphExecutor(self.dock, rl, tracer=self.tracer,
                                       faults=faults)
         self.last_run = None
+
+    def _init_state(self, init, *args) -> None:
+        """Make the weights with ``init(*args)`` as one compiled program,
+        directly in the update-stage (train) layout; build the resharder
+        between the two stage layouts; create the optimizer state in the
+        same layout.  On a mesh nothing is first gathered onto one device."""
+        shapes = jax.eval_shape(init, *args)
+        tspecs = param_specs(self.cfg, shapes, self.mesh, stage="train")
+        gspecs = param_specs(self.cfg, shapes, self.mesh, stage="gen",
+                             gen_mode="tp")
+        self.resharder = Resharder(self.mesh, tspecs, gspecs,
+                                   use_swap=self.rl.use_allgather_swap)
+        shardings = self.resharder.train_shardings
+        self.params = jax.jit(init, out_shardings=shardings)(*args)
+        self.opt_state = jax.jit(adamw_init, out_shardings=AdamWState(
+            step=NamedSharding(self.mesh, P()), mu=shardings,
+            nu=shardings))(self.params)
 
     def _build_graph(self) -> RLGraph:
         return build_grpo_graph(self.actor.node, self.ref.node,

@@ -7,6 +7,11 @@ accumulator.  Tile sizes are MXU-aligned (multiples of 128 at full scale).
 The q/k block loop bound is static; causal and sliding-window masking skip
 out-of-range blocks by zero-masking (interpret-mode friendly; on real TPU the
 ``when`` predication prunes them).
+
+Backward: ``jax.custom_vjp`` whose backward is the jnp flash path's VJP
+(``ops._flash``: chunked online-softmax forward for the log-sum-exp, then
+the chunked recompute backward), recomputed from the saved q, k, v — O(S)
+memory, no score matrix materialized.
 """
 from __future__ import annotations
 
@@ -76,6 +81,36 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     scale: float | None = None, block_q: int = 128,
                     block_k: int = 128, interpret: bool = False):
     """q: (B, Sq, H, d); k, v: (B, Sk, KV, d).  Returns (B, Sq, H, d)."""
+    scale = scale if scale is not None else 1.0 / float(np.sqrt(q.shape[-1]))
+    return _flash(q, k, v, causal, window, scale, block_q, block_k, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, window, scale, block_q, block_k, interpret):
+    return _flash_fwd_call(q, k, v, causal=causal, window=window, scale=scale,
+                           block_q=block_q, block_k=block_k,
+                           interpret=interpret)
+
+
+def _flash_fwd(q, k, v, causal, window, scale, block_q, block_k, interpret):
+    return (_flash(q, k, v, causal, window, scale, block_q, block_k,
+                   interpret), (q, k, v))
+
+
+def _flash_bwd(causal, window, scale, block_q, block_k, interpret, res, dout):
+    from repro.kernels import ops
+
+    _, vjp = jax.vjp(lambda q, k, v: ops._flash(q, k, v, causal, window,
+                                                scale), *res)
+    return vjp(dout)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _flash_fwd_call(q, k, v, *, causal: bool = True, window: int = 0,
+                    scale: float | None = None, block_q: int = 128,
+                    block_k: int = 128, interpret: bool = False):
     b, sq, h, d = q.shape
     _, sk, kv, _ = k.shape
     assert h % kv == 0, f"query heads {h} must group evenly over {kv} KV heads"

@@ -11,11 +11,13 @@ honest.
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
 
@@ -30,13 +32,53 @@ def _use_pallas() -> bool:
 # elementwise fusions
 # ---------------------------------------------------------------------------
 
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def _shard_axes(names, dim: int):
+    """The ambient mesh's axes among ``names`` to split a dim of size
+    ``dim`` over, or None when there is no mesh or they do not divide it
+    (the dim is then replicated)."""
+    mesh = ambient_mesh()
+    if mesh is None:
+        return None
+    axes = tuple(a for a in names if a in mesh.axis_names)
+    n = 1
+    for a in axes:
+        n *= mesh.shape[a]
+    if not axes or n == 1 or dim % n:
+        return None
+    return axes if len(axes) > 1 else axes[0]
+
+
+def _per_shard(kernel, args, in_specs, out_spec):
+    """Call a Pallas kernel on each device's block of ``args``.
+
+    XLA cannot partition a Pallas (Mosaic) kernel, so under a multi-device
+    ambient mesh (``jax.set_mesh``) the kernel runs inside ``shard_map``
+    with the given specs; one device calls it directly.  The specs split
+    only dims the kernel treats independently (rows, batch, heads)."""
+    mesh = ambient_mesh()
+    if mesh is None or mesh.size == 1:
+        return kernel(*args)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_spec, check_vma=False)(*args)
+
+
+_BATCH_AXES = ("pod", "data")
+
+
 def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
     if _use_pallas() and x.ndim >= 2:
         from repro.kernels import rmsnorm as _k
 
         shape = x.shape
-        out = _k.rmsnorm(x.reshape(-1, shape[-1]), w, eps=eps,
-                         interpret=not jax.default_backend() == "tpu")
+        x2 = x.reshape(-1, shape[-1])
+        spec = P(_shard_axes(_BATCH_AXES, x2.shape[0]), None)
+        out = _per_shard(
+            lambda x, w: _k.rmsnorm(x, w, eps=eps, interpret=_interpret()),
+            (x2, w), (spec, P(None)), spec)
         return out.reshape(shape)
     return ref.rmsnorm(x, w, eps)
 
@@ -46,8 +88,12 @@ def swiglu(gate: jnp.ndarray, up: jnp.ndarray) -> jnp.ndarray:
         from repro.kernels import swiglu as _k
 
         shape = gate.shape
-        out = _k.swiglu(gate.reshape(-1, shape[-1]), up.reshape(-1, shape[-1]),
-                        interpret=not jax.default_backend() == "tpu")
+        g2, u2 = gate.reshape(-1, shape[-1]), up.reshape(-1, shape[-1])
+        spec = P(_shard_axes(_BATCH_AXES, g2.shape[0]),
+                 _shard_axes(("model",), g2.shape[1]))
+        out = _per_shard(
+            lambda g, u: _k.swiglu(g, u, interpret=_interpret()),
+            (g2, u2), (spec, spec), spec)
         return out.reshape(shape)
     return ref.swiglu(gate, up)
 
@@ -60,8 +106,14 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarra
         c, s = cos, sin
         if c.ndim == 4:            # callers pass a broadcast head axis
             c, s = c[:, :, 0], s[:, :, 0]
-        return _k.apply_rope(x, c, s,
-                             interpret=not jax.default_backend() == "tpu")
+        b, sq, h, d = x.shape
+        c = jnp.broadcast_to(c, (b, sq, d // 2))
+        s = jnp.broadcast_to(s, (b, sq, d // 2))
+        bax, hax = _shard_axes(_BATCH_AXES, b), _shard_axes(("model",), h)
+        return _per_shard(
+            lambda x, c, s: _k.apply_rope(x, c, s, interpret=_interpret()),
+            (x, c, s), (P(bax, None, hax, None), P(bax, None, None),
+                        P(bax, None, None)), P(bax, None, hax, None))
     return ref.rope(x, cos, sin)
 
 
@@ -238,14 +290,10 @@ def _attention_batch_spec(b: int, h: int, sq: int = 0):
        cheap) — per-device score compute drops by the model-axis size.
 
     Returns (q_spec, kv_spec) or None."""
-    from jax.sharding import PartitionSpec as P
-
     mesh = ambient_mesh()
     if mesh is None:
         return None
-    names = list(mesh.axis_names)
-    sizes = (dict(zip(names, mesh.axis_sizes)) if hasattr(mesh, "axis_sizes")
-             else {a: mesh.shape[a] for a in names})
+    sizes = dict(mesh.shape)
     mdl = sizes.get("model", 1)
     if mdl <= 1 or h % mdl == 0:
         return None                       # head sharding works; leave to XLA
@@ -280,9 +328,16 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
     if _use_pallas():
         from repro.kernels import flash_attention as _k
 
-        out = _k.flash_attention(
-            q, k, v, causal=causal, window=window, scale=scale,
-            interpret=not jax.default_backend() == "tpu")
+        # heads split only where both q and kv heads divide: contiguous head
+        # blocks then keep every GQA group on one device
+        blk = P(_shard_axes(_BATCH_AXES, q.shape[0]), None,
+                _shard_axes(("model",), math.gcd(q.shape[2], k.shape[2])),
+                None)
+        out = _per_shard(
+            lambda q, k, v: _k.flash_attention(
+                q, k, v, causal=causal, window=window, scale=scale,
+                interpret=_interpret()),
+            (q, k, v), (blk, blk, blk), blk)
     else:
         out = _flash(q, k, v, causal, window, scale)
     if spec is not None:
@@ -295,27 +350,14 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
 
 
 def ambient_mesh():
-    """The mesh active at trace time: the new-style abstract mesh, or the
-    legacy ``with mesh:`` thread-resources mesh.  None when single-device."""
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return m
-    except Exception:
-        pass
-    try:
-        from jax._src import mesh as mesh_lib
-
-        pm = mesh_lib.thread_resources.env.physical_mesh
-        if pm is not None and pm.axis_names:
-            return pm
-    except Exception:
-        pass
-    return None
+    """The mesh set for tracing (``jax.set_mesh``), as an AbstractMesh.
+    None when no mesh is set (single-device tests / examples)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _maybe_constrain(x, spec):
-    """with_sharding_constraint when an ambient mesh provides the axes;
+    """with_sharding_constraint when the ambient mesh provides the axes;
     no-op otherwise (single-device tests / examples)."""
     mesh = ambient_mesh()
     if mesh is None:
@@ -325,14 +367,7 @@ def _maybe_constrain(x, spec):
         flat.extend(ax if isinstance(ax, tuple) else [ax])
     if any(ax is not None and ax not in mesh.axis_names for ax in flat):
         return x
-    try:
-        from jax.sharding import AbstractMesh, NamedSharding
-
-        if isinstance(mesh, AbstractMesh):
-            return jax.lax.with_sharding_constraint(x, spec)
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 def decode_attention(q, k_cache, v_cache, valid_mask, *,
@@ -348,8 +383,6 @@ def decode_attention(q, k_cache, v_cache, valid_mask, *,
     the contraction is local and only the (tiny) score partial-sums are
     all-reduced — instead of XLA re-gathering the whole cache per step.
     """
-    from jax.sharding import PartitionSpec as P
-
     b, _, h, d = q.shape
     _, s, kv, _ = k_cache.shape
     g = h // kv
@@ -359,10 +392,7 @@ def decode_attention(q, k_cache, v_cache, valid_mask, *,
     # under SPMD, re-gather) a full-precision copy of the whole cache.
     qg = (q.reshape(b, kv, g, d) * scale).astype(k_cache.dtype)
     mesh = ambient_mesh()
-    mdl = dict(zip(mesh.axis_names,
-                   getattr(mesh, "axis_sizes", None)
-                   or [mesh.shape[a] for a in mesh.axis_names])
-               ).get("model", 1) if mesh is not None else 1
+    mdl = mesh.shape.get("model", 1) if mesh is not None else 1
     if mdl > 1 and kv % mdl and d % mdl == 0:
         # hd-sharded-cache regime (see sharding/rules.py)
         qg = _maybe_constrain(qg, P(None, None, None, "model"))
@@ -453,7 +483,7 @@ def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, tables, pos, *,
         out = _k.paged_decode_attention(
             q[None, :, 0], k_new[None], v_new[None], pool_k[None],
             pool_v[None], tables, pos, block_size=block_size, window=window,
-            scale=scale, interpret=not jax.default_backend() == "tpu")
+            scale=scale, interpret=_interpret())
         return out[0][:, None]
     return ref.paged_decode_attention(q, k_new, v_new, pool_k, pool_v, tables,
                                       pos, block_size=block_size,
@@ -476,7 +506,7 @@ def gmm(x: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
         from repro.kernels import gmm as _k
 
         return _k.gmm(x, w, group_sizes, tile_t=tile_t,
-                      interpret=not jax.default_backend() == "tpu")
+                      interpret=_interpret())
     t = x.shape[0]
     e = w.shape[0]
     bounds = jnp.cumsum(group_sizes)

@@ -66,7 +66,7 @@ def _paged_decode_kernel(tbl_ref, pos_ref, q_ref, kn_ref, vn_ref, kb_ref,
 
     @pl.when(start < p)       # block holds at least one cached row (< pos)
     def _block():
-        q = q_ref[0, 0].reshape(kv, g, hd).astype(jnp.float32) * scale
+        q = q_ref[0, 0, 0].reshape(kv, g, hd).astype(jnp.float32) * scale
         kblk = kb_ref[0, 0].reshape(block_size, kv, hd).astype(jnp.float32)
         vblk = vb_ref[0, 0].reshape(block_size, kv, hd).astype(jnp.float32)
         kpos = start + jax.lax.broadcasted_iota(jnp.int32, (block_size, 1),
@@ -88,9 +88,9 @@ def _paged_decode_kernel(tbl_ref, pos_ref, q_ref, kn_ref, vn_ref, kb_ref,
 
     @pl.when(j == nb - 1)     # fold the in-flight token, then normalize
     def _final():
-        q = q_ref[0, 0].reshape(kv, g, hd).astype(jnp.float32) * scale
-        kn = kn_ref[0, 0].reshape(kv, hd).astype(jnp.float32)
-        vn = vn_ref[0, 0].reshape(kv, hd).astype(jnp.float32)
+        q = q_ref[0, 0, 0].reshape(kv, g, hd).astype(jnp.float32) * scale
+        kn = kn_ref[0, 0, 0].reshape(kv, hd).astype(jnp.float32)
+        vn = vn_ref[0, 0, 0].reshape(kv, hd).astype(jnp.float32)
         s1 = jnp.einsum("kgd,kd->kg", q, kn,
                         preferred_element_type=jnp.float32)
         m_prev = m_ref[...]
@@ -99,7 +99,7 @@ def _paged_decode_kernel(tbl_ref, pos_ref, q_ref, kn_ref, vn_ref, kb_ref,
         p1 = jnp.exp(s1 - m_new)
         l = l_ref[...] * corr + p1
         acc = acc_ref[...] * corr[..., None] + p1[..., None] * vn[:, None]
-        o_ref[0, 0] = (acc / l[..., None]).reshape(kv * g * hd).astype(
+        o_ref[0, 0, 0] = (acc / l[..., None]).reshape(kv * g * hd).astype(
             o_ref.dtype)
 
 
@@ -134,24 +134,31 @@ def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, tables, pos, *,
     scale = scale if scale is not None else 1.0 / float(np.sqrt(hd))
     poolk4 = pool_k.reshape(n, -1, block_size, kv * hd)
     poolv4 = pool_v.reshape(n, -1, block_size, kv * hd)
-    q3 = q.reshape(n, s, h * hd)
-    kn3 = k_new.reshape(n, s, kv * hd)
-    vn3 = v_new.reshape(n, s, kv * hd)
+    # per-slot operands carry a unit axis so each block's last two dims
+    # (1, width) equal the array's: the chip's tiling rule (last two block
+    # dims divisible by (8, 128) or whole) refuses a (1, width) block over
+    # an (S, width) array once S > 1
+    q4 = q.reshape(n, s, 1, h * hd)
+    kn4 = k_new.reshape(n, s, 1, kv * hd)
+    vn4 = v_new.reshape(n, s, 1, kv * hd)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n, s, mb),
         in_specs=[
-            pl.BlockSpec((1, 1, h * hd), lambda l, i, j, tbl, ps: (l, i, 0)),
-            pl.BlockSpec((1, 1, kv * hd), lambda l, i, j, tbl, ps: (l, i, 0)),
-            pl.BlockSpec((1, 1, kv * hd), lambda l, i, j, tbl, ps: (l, i, 0)),
+            pl.BlockSpec((1, 1, 1, h * hd),
+                         lambda l, i, j, tbl, ps: (l, i, 0, 0)),
+            pl.BlockSpec((1, 1, 1, kv * hd),
+                         lambda l, i, j, tbl, ps: (l, i, 0, 0)),
+            pl.BlockSpec((1, 1, 1, kv * hd),
+                         lambda l, i, j, tbl, ps: (l, i, 0, 0)),
             pl.BlockSpec((1, 1, block_size, kv * hd),
                          lambda l, i, j, tbl, ps: (l, tbl[i, j], 0, 0)),
             pl.BlockSpec((1, 1, block_size, kv * hd),
                          lambda l, i, j, tbl, ps: (l, tbl[i, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, h * hd),
-                               lambda l, i, j, tbl, ps: (l, i, 0)),
+        out_specs=pl.BlockSpec((1, 1, 1, h * hd),
+                               lambda l, i, j, tbl, ps: (l, i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((kv, g, hd), jnp.float32),   # acc
             pltpu.VMEM((kv, g), jnp.float32),       # m (running max)
@@ -162,7 +169,7 @@ def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, tables, pos, *,
         functools.partial(_paged_decode_kernel, block_size=block_size, nb=mb,
                           kv=kv, g=g, hd=hd, window=window, scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, s, h * hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, s, 1, h * hd), q.dtype),
         interpret=interpret,
-    )(tables, pos, q3, kn3, vn3, poolk4, poolv4)
+    )(tables, pos, q4, kn4, vn4, poolk4, poolv4)
     return out.reshape(n, s, h, hd)

@@ -3,6 +3,10 @@
 Tiles rows into VMEM blocks of (block_rows, d); each program computes the
 mean-square and scales in one pass (one HBM read, one HBM write — the fusion
 the paper's Ascend kernel provides).
+
+Backward: ``jax.custom_vjp`` whose backward is the VJP of the same f32 math
+in jnp (``_rmsnorm_math``), recomputed from the saved inputs — so the update
+step differentiates through the Pallas forward on the chip.
 """
 from __future__ import annotations
 
@@ -17,17 +21,46 @@ from jax.experimental import pallas as pl
 VMEM_BOUNDS = {"d": 8192}
 
 
-def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
-    x = x_ref[...].astype(jnp.float32)
+def _rmsnorm_math(x, w, eps: float):
+    """The kernel's arithmetic (f32 throughout, one cast at the end)."""
+    x = x.astype(jnp.float32)
     var = jnp.mean(x * x, axis=-1, keepdims=True)
-    y = x * jax.lax.rsqrt(var + eps)
-    o_ref[...] = (y * w_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
+    return x * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)
+
+
+def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
+    o_ref[...] = _rmsnorm_math(x_ref[...], w_ref[...], eps).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, *, eps: float = 1e-5,
             block_rows: int = 128, interpret: bool = False) -> jnp.ndarray:
     """x: (rows, d), w: (d,).  d should be a multiple of 128 on real TPU."""
+    return _rmsnorm(x, w, eps, block_rows, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _rmsnorm(x, w, eps, block_rows, interpret):
+    return _rmsnorm_fwd_call(x, w, eps=eps, block_rows=block_rows,
+                             interpret=interpret)
+
+
+def _rmsnorm_fwd(x, w, eps, block_rows, interpret):
+    return _rmsnorm(x, w, eps, block_rows, interpret), (x, w)
+
+
+def _rmsnorm_bwd(eps, block_rows, interpret, res, dout):
+    x, w = res
+    _, vjp = jax.vjp(lambda x, w: _rmsnorm_math(x, w, eps), x, w)
+    dx, dw = vjp(dout.astype(jnp.float32))
+    return dx.astype(x.dtype), dw.astype(w.dtype)
+
+
+_rmsnorm.defvjp(_rmsnorm_fwd, _rmsnorm_bwd)
+
+
+def _rmsnorm_fwd_call(x, w, *, eps: float = 1e-5, block_rows: int = 128,
+                      interpret: bool = False) -> jnp.ndarray:
     rows, d = x.shape
     block_rows = min(block_rows, rows)
     while rows % block_rows:

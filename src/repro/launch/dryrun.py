@@ -54,7 +54,7 @@ def run_pair(arch: str, shape_name: str, *, multi_pod: bool = False,
             print(f"SKIP {arch} × {shape_name} × {mesh_name}: {e}")
         return rec
 
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=in_shard,
                           out_shardings=out_shard).lower(*args)
         t_lower = time.perf_counter() - t0
@@ -97,7 +97,7 @@ def run_reshard(arch: str, *, multi_pod: bool = False, gen_mode: str = "tp",
     mesh_name = "x".join(str(s) for s in mesh.devices.shape)
     fn, args, in_shard, out_shard, meta = reshard_program(
         arch, mesh, gen_mode=gen_mode)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn, in_shardings=in_shard,
                            out_shardings=out_shard).lower(*args).compile()
     stats = analysis.parse_collectives(compiled.as_text())
@@ -163,7 +163,7 @@ def run_pipeline_demo(arch: str = "yi-6b", microbatches: int = 8,
     tok = jax.ShapeDtypeStruct((b, s), jnp.int32)
     cos, sin = jax.eval_shape(
         lambda: T._rope(cfg, T._positions(cfg, mb, s)))
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(grad_fn).lower(
             pstruct, tok,
             jax.ShapeDtypeStruct(cos.shape, cos.dtype),

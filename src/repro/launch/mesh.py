@@ -6,35 +6,29 @@ Multi-pod:   (2, 16, 16) over ("pod", "data", "model") — 512 chips.
 FUNCTIONS (not module constants) so importing this module never touches
 jax device state — only ``dryrun.py`` sets the 512-host-device XLA flag.
 
-``make_mesh`` is the ONE version-tolerant constructor: newer jax exposes
-``jax.sharding.AxisType`` and accepts ``axis_types=``; jax 0.4.x does not
-(meshes are implicitly Auto there), so we feature-detect once and every
-call site in src/, examples/, benchmarks/ and tests/ goes through here.
+Every mesh here has Auto axes: XLA propagates shardings from the
+``sharding/rules.py`` specs and the ``ops`` constraints, the mode those
+rules are written for (``jax.make_mesh`` now defaults to Explicit axes).
 """
 from __future__ import annotations
 
+import math
+
 import jax
+from jax.experimental import mesh_utils
+from jax.sharding import AbstractMesh, Mesh
 
 
 def make_mesh(shape, axes):
-    """Version-tolerant ``jax.make_mesh`` with Auto axis types everywhere
-    the installed jax supports them."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """A device mesh of ``shape`` over the first ``prod(shape)`` devices,
+    laid out for the physical topology."""
+    devices = jax.devices()[:math.prod(shape)]
+    return Mesh(mesh_utils.create_device_mesh(shape, devices), axes)
 
 
 def make_abstract_mesh(shape, axes):
-    """Version-tolerant ``jax.sharding.AbstractMesh`` (device-free mesh for
-    sharding rules).  Newer jax: ``AbstractMesh(shape, axes, axis_types=…)``;
-    jax 0.4.x: ``AbstractMesh(((name, size), …))``."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.sharding.AbstractMesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    """A device-free mesh for sharding rules (dry runs, spec tests)."""
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,12 +1,13 @@
 """Training launcher — end-to-end GRPO on a selectable architecture.
 
-CPU-scale entry point (runs for real):
+Smoke widths (runs on the CPU):
     PYTHONPATH=src python -m repro.launch.train --arch yi-6b --smoke \
         --iterations 50 --global-batch 8
 
-Production entry point (same code path, production mesh — requires a real
-TPU slice; on this container use ``--dry-run`` which delegates to dryrun.py):
-    python -m repro.launch.train --arch qwen2.5-32b --mesh 16x16
+Without ``--smoke`` it trains the published config on one device, which
+must hold it: yi-6b's 32 layers do not fit one 16 GB TPU v5e chip
+(``chip_smoke.py`` runs its published widths at 2 layers).  Production
+meshes are compiled, not run, by ``python -m repro.launch.dryrun``.
 """
 from __future__ import annotations
 
@@ -108,6 +109,8 @@ def main() -> None:
                  "it cannot be combined with --algorithm ppo")
 
     # imports deferred so --help never initializes jax
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     from repro.checkpoint import (is_train_state, load_pytree,
                                   load_train_state, save_pytree,
                                   save_train_state)

@@ -258,9 +258,7 @@ def constrain_batch(x: jnp.ndarray) -> jnp.ndarray:
     mesh = ops.ambient_mesh()
     if mesh is None:
         return x
-    names = list(mesh.axis_names)
-    sizes = (dict(zip(names, mesh.axis_sizes)) if hasattr(mesh, "axis_sizes")
-             else {a: mesh.shape[a] for a in names})
+    sizes = dict(mesh.shape)
     axes = tuple(a for a in ("pod", "data") if a in sizes)
     n = 1
     for a in axes:

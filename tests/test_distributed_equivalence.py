@@ -40,7 +40,7 @@ for arch in ("yi-6b", "mixtral-8x7b", "mamba2-1.3b"):
                              is_leaf=lambda x: isinstance(x, P))
     pd = jax.device_put(params, shardings)
     bd = jax.device_put(batch, NamedSharding(mesh, P("data", None)))
-    with mesh:
+    with jax.set_mesh(mesh):
         logits8, _ = jax.jit(lambda p, b: m.forward(p, cfg, b))(pd, bd)
     err = float(np.max(np.abs(np.asarray(logits1) - np.asarray(logits8))))
     scale = float(np.max(np.abs(np.asarray(logits1))))
