@@ -28,6 +28,7 @@ import numpy as np
 
 import check
 import flops
+import hostload
 import program
 import weights
 from reference.grpo import GRPOReference, named, response_mask
@@ -120,8 +121,12 @@ def instrument(trainer, run, rec: dict) -> None:
     faults = run.faults
 
     def gen_(*a, **kw):
+        host = rec.get("host")
+        m = host.mark() if host else None
         with run.span("generate"):
             out = generate(*a, **kw)
+        if host:
+            rec["gen_host"] = host.since(m)
         if rec["keep"] and "token" in faults:
             pl = out.tokens.shape[1] - out.gen_logp.shape[1]
             out.tokens[:, pl + 1] = (out.tokens[:, pl + 1] + 1) % vocab
@@ -192,9 +197,12 @@ def drive(run, devices) -> dict:
 
     # the window
     its = []
+    host = rec["host"] = hostload.HostLoad()
+    h0 = host.mark()
     run.start_window()
     with run.traced():
         while True:
+            hm = host.mark()
             t0 = time.perf_counter()
             with run.span("iteration"):
                 st = trainer.iteration(G)
@@ -210,16 +218,19 @@ def drive(run, devices) -> dict:
                 "gen_s": st.gen_time, "infer_s": st.infer_time,
                 "update_s": st.update_time,
                 "reshard_s": st.reshard.get("wall_s", 0.0),
+                "host": host.since(hm), "gen_host": rec.pop("gen_host"),
             })
             if time.perf_counter() - run.window_t0 >= run.seconds:
                 break
     compiles = run.compiles_in_window()
+    host_load = host.since(h0)
+    host.close()
     g = trainer.executor.metrics
     failed = int(g.value("graph.quarantined"))
     window = {
         "kind": "grpo", "iterations": its,
         "attempted": len(its) * G * N, "failed": failed,
-        "window_compiles": compiles,
+        "window_compiles": compiles, "host": host_load,
         "memory_peak_bytes": program.memory_peak_bytes(devices),
     }
     window["trace"] = run.reduce_trace(run.chips)

@@ -2,18 +2,22 @@
 
 Traffic file keys: ``slots``, ``block_size``, ``group_size`` (N),
 ``groups_in_flight``, ``prompt_len`` [lo, hi], ``max_new`` {median, sigma,
-min, max} (lognormal, clipped), ``temperature``, ``fill_steps``,
+min, max} (lognormal, clipped), ``temperature``, ``fill_groups``,
 ``check_requests``.  A group is one prompt submitted N times, each with
 its own ``max_new``; a new group goes in when a whole group has finished,
-as GRPO's advantages wait for whole groups.  Sizes come from a fixed
-catalogue (the distributions' quantiles) that every seed shares; the seed
-orders it and draws the token ids, so every seed offers the same work.
+as GRPO's advantages wait for whole groups.  Sizes come in blocks of
+groups that each hold the distributions' quantiles (``Stream``); the seed
+orders each block and draws the token ids, so every seed offers the same
+work.
 
 Set-up makes the weights from the seed (one compiled call), warms every
 program the stream uses (whole-prompt prefill at each length bucket, the
 continuation prefill of a shared prefix at each tail bucket, the decode
-step over all slots), then fills the stream for ``fill_steps`` steps.  The
-window steps the engine until ``--seconds`` have passed.  After it, a
+step over all slots), then steps the stream until ``fill_groups`` groups
+have finished, which brings it to its steady state: every slot busy, the
+queue near its stationary depth, groups finishing at a steady rate.  The
+window steps the engine until ``--seconds`` have passed, and records each
+step's live slots, queue depth and groups submitted.  After it, a
 sample of the finished requests drawn from the seed, the longest among
 them, is scored by the plain reference: the check is the widest gap
 between the engine's log-probability of each served token and the
@@ -29,37 +33,44 @@ import numpy as np
 
 import check
 import flops
+import hostload
 import program
 import weights
 from reference import llama
 
-CATALOGUE_GROUPS = 4096
+BLOCK_GROUPS = 16
 
 
 class Stream:
+    """Groups in blocks of ``BLOCK_GROUPS``: every block holds the same
+    sizes, the quantiles of the prompt-length and ``max_new``
+    distributions, in an order the seed draws anew for each block.  A run
+    uses some ten blocks, so every seed offers the same work, and so does
+    every stretch of a few blocks."""
+
     def __init__(self, t: dict, seed: int, vocab: int):
-        n = t["group_size"]
-        lo, hi = t["prompt_len"]
-        q = (np.arange(CATALOGUE_GROUPS) + 0.5) / CATALOGUE_GROUPS
-        self.prompt_lens = np.rint(lo + (hi - lo) * q).astype(int)
-        mn = t["max_new"]
-        qn = (np.arange(CATALOGUE_GROUPS * n) + 0.5) / (CATALOGUE_GROUPS * n)
         from statistics import NormalDist
 
-        z = np.asarray([NormalDist().inv_cdf(x) for x in qn])
+        n, b = t["group_size"], BLOCK_GROUPS
+        lo, hi = t["prompt_len"]
+        self.prompt_lens = np.rint(
+            lo + (hi - lo) * (np.arange(b) + 0.5) / b).astype(int)
+        mn = t["max_new"]
+        z = np.asarray([NormalDist().inv_cdf((k + 0.5) / (b * n))
+                        for k in range(b * n)])
         self.max_news = np.clip(np.rint(mn["median"] * np.exp(mn["sigma"] * z)),
                                 mn["min"], mn["max"]).astype(int)
         self.rng = np.random.default_rng(seed)
-        self.rng.shuffle(self.prompt_lens)
-        self.rng.shuffle(self.max_news)
         self.n, self.vocab, self.i = n, vocab, 0
 
     def next(self):
-        k = self.i % CATALOGUE_GROUPS
+        n, k = self.n, self.i % BLOCK_GROUPS
+        if k == 0:
+            self.lens = self.rng.permutation(self.prompt_lens)
+            self.news = self.rng.permutation(self.max_news)
         self.i += 1
-        prompt = self.rng.integers(0, self.vocab, self.prompt_lens[k],
-                                   dtype=np.int32)
-        return prompt, self.max_news[k * self.n:(k + 1) * self.n]
+        prompt = self.rng.integers(0, self.vocab, self.lens[k], dtype=np.int32)
+        return prompt, self.news[k * n:(k + 1) * n]
 
 
 def warm(eng, params, t: dict, vocab: int, seed: int) -> None:
@@ -111,7 +122,7 @@ def drive(run, devices) -> dict:
     pending: dict[int, int] = {}        # group -> requests not finished
     group_of: dict[int, int] = {}
     finished: dict[int, object] = {}
-    submitted = [0]
+    count = {"requests": 0, "groups": 0, "done": 0}
 
     def submit_group():
         prompt, news = stream.next()
@@ -120,7 +131,8 @@ def drive(run, devices) -> dict:
         for mn in news:
             rid = eng.submit(prompt, max_new=int(mn))
             group_of[rid] = g
-            submitted[0] += 1
+            count["requests"] += 1
+        count["groups"] += 1
 
     def step(keep: bool):
         with run.span("engine.step"):
@@ -134,29 +146,43 @@ def drive(run, devices) -> dict:
             pending[g] -= 1
             if pending[g] == 0:
                 del pending[g]
+                count["done"] += 1
                 with run.span("submit_group"):
                     submit_group()
         return outs
 
     for _ in range(t["groups_in_flight"]):
         submit_group()
-    for _ in range(t["fill_steps"]):
+    fill_steps = 0
+    while count["done"] < t["fill_groups"]:
         step(keep=False)
+        fill_steps += 1
 
     s0 = eng.stats()
+    c0 = dict(count)
+    log = []                    # per step: end time, live, queue, groups in
     steps_ctx = []
+    host = hostload.HostLoad()
+    h0 = host.mark()
     run.start_window()
     with run.traced():
         while True:
+            groups = count["groups"]
             outs = step(keep=True)
+            now = time.perf_counter()
+            log.append((now - run.window_t0,
+                        eng.sched.num_running + len(outs),
+                        eng.sched.num_pending, count["groups"] - groups))
             if run.trace:
                 ctx = [r.cache_len for r in eng.sched.running.values()]
                 ctx += [len(o.prompt) + len(o.gen) - 1 for o in outs]
                 steps_ctx.append(ctx)
-            if time.perf_counter() - run.window_t0 >= run.seconds:
+            if now - run.window_t0 >= run.seconds:
                 break
     window_s = time.perf_counter() - run.window_t0
     compiles = run.compiles_in_window()
+    host_load = host.since(h0)
+    host.close()
     s1 = eng.stats()
     delta = {k: s1[k] - s0[k] for k in ("steps", "prefill_tokens",
                                         "shared_prefill_tokens",
@@ -164,8 +190,9 @@ def drive(run, devices) -> dict:
                                         "submitted", "finished")}
     window = {
         "kind": "rollout", "window_s": window_s, "counters": delta,
-        "attempted": submitted[0], "failed": 0,
-        "window_compiles": compiles,
+        "attempted": count["requests"], "failed": 0,
+        "window_compiles": compiles, "host": host_load,
+        "stream": stream_summary(log, fill_steps, c0, count),
         "memory_peak_bytes": program.memory_peak_bytes(devices),
     }
     if run.trace:
@@ -177,6 +204,33 @@ def drive(run, devices) -> dict:
     program.release()
     window["checks"] = compare(c, t, run, list(finished.values()))
     return window
+
+
+def stream_summary(log, fill_steps, c0, c1, parts=4) -> dict:
+    """The stream over the window, whole and in ``parts`` equal stretches
+    of steps: live slots and queue depth per step, groups submitted, and
+    the milliseconds a step took; flat stretches show a steady state."""
+    ends = np.asarray([r[0] for r in log])
+    live, queue, groups = (np.asarray([r[k] for r in log]) for k in (1, 2, 3))
+    dur = np.diff(np.concatenate([[0.0], ends]))
+    quarters = []
+    for idx in np.array_split(np.arange(len(log)), parts):
+        if len(idx):
+            quarters.append({
+                "steps": int(len(idx)),
+                "step_ms": float(1e3 * dur[idx].mean()),
+                "live_slots": float(live[idx].mean()),
+                "queue": float(queue[idx].mean()),
+                "groups_submitted": int(groups[idx].sum())})
+    return {"fill_steps": fill_steps, "fill_groups_done": c0["done"],
+            "steps": len(log),
+            "groups_submitted": c1["groups"] - c0["groups"],
+            "groups_done": c1["done"] - c0["done"],
+            "live_slots_mean": float(live.mean()),
+            "live_slots_min": int(live.min()),
+            "queue_mean": float(queue.mean()),
+            "queue_min": int(queue.min()),
+            "parts": quarters}
 
 
 def window_flops(dims, delta, steps_ctx, lo, hi) -> float:

@@ -32,7 +32,6 @@ import time
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
-CACHE_DIR = os.path.join(ROOT, ".jax_cache")
 
 
 class NoChip(RuntimeError):
@@ -109,11 +108,15 @@ class CompileCounter:
 
 
 def use_compile_cache() -> None:
-    """JAX's persistent cache at a fixed path inside the checkout, every
-    program cached however short its compile."""
+    """The program's persistent compilation cache (``JAX_COMPILATION_CACHE_DIR``
+    where set, else ``.jax_cache`` in the checkout), with every program
+    cached however short its compile: the small generation programs too."""
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    import program  # noqa: F401  (puts the program's ``src`` on the path)
+    from repro.launch.cache import use_compile_cache as program_cache
+
+    program_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
@@ -221,14 +224,16 @@ def parse(argv):
 
 
 def main(argv, *, t_start: float | None = None, require_chip: bool = True,
-         overrides: dict | None = None, keep: list | None = None) -> int:
-    """``require_chip=False`` and ``overrides`` are for the CPU tests:
-    they skip the look for a TPU and shrink the configuration.  ``keep``
-    (a list) receives the run's ``Run``, whose ``readings`` and
-    ``control_readings`` the calibration reads."""
+         overrides: dict | None = None, keep: list | None = None,
+         bench: dict | None = None) -> int:
+    """``require_chip=False``, ``overrides`` and ``bench`` are for the CPU
+    tests: they skip the look for a TPU, shrink the configuration and name
+    the cells (``BENCHMARK.json`` where not given).  ``keep`` (a list)
+    receives the run's ``Run``, whose ``readings`` and ``control_readings``
+    the calibration reads."""
     t_start = time.perf_counter() if t_start is None else t_start
     args = parse(argv)
-    bench = benchmark()
+    bench = benchmark() if bench is None else bench
     cell = cell_spec(bench, args.workload)
     run = Run(cell, args, t_start, overrides)
     if keep is not None:
@@ -271,10 +276,16 @@ def main(argv, *, t_start: float | None = None, require_chip: bool = True,
     if run.trace and window.get("trace"):
         out["breakdown"] = {"device_ops": window["trace"]["device_ops"],
                             "idle_gaps": window["trace"]["idle_gaps"]}
+    if "stream" in window:
+        out["stream"] = {k: v for k, v in window["stream"].items()
+                         if k != "parts"}
     out["checks"] = {c["name"]: {"value": c["value"], "limit": c["limit"]}
                      for c in checks}
     for it in window.get("iterations", []):
         print(f"bench iteration: {json.dumps(it)}", file=sys.stderr)
+    for part in window.get("stream", {}).get("parts", []):
+        print(f"bench stream part: {json.dumps(part)}", file=sys.stderr)
+    print(f"bench host: {json.dumps(window.get('host'))}", file=sys.stderr)
     print(f"bench: compiles inside the window: "
           f"{json.dumps(window.get('window_compiles'))}", file=sys.stderr)
     for name, v in sorted((run.readings or {}).items()):

@@ -46,7 +46,9 @@ def main(argv=None) -> int:
                    "result": json.loads(lines[-1]) if lines else None,
                    "notes": [ln for ln in p.stderr.splitlines()
                              if ln.startswith(("bench iteration",
-                                               "bench: compiles"))],
+                                               "bench: compiles",
+                                               "bench stream part",
+                                               "bench host"))],
                    "stderr_tail": p.stderr[-2000:] if p.returncode else ""}
             rows.append(row)
             print(json.dumps(row), flush=True)
