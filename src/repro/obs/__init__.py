@@ -1,10 +1,11 @@
-"""repro.obs — zero-dependency telemetry: structured tracing + metrics.
+"""repro.obs — telemetry: structured tracing + metrics.
 
 Two small, orthogonal pieces (see docs/observability.md for the catalog):
 
   * ``Tracer``          — process-local structured event log (spans /
     instants / counters on a monotonic clock) with a Chrome-trace /
-    Perfetto JSON exporter.  Thread-safe; a DISABLED tracer is a cheap
+    Perfetto JSON exporter; enabled spans are mirrored into the JAX
+    profiler's trace.  Thread-safe; a DISABLED tracer is a cheap
     no-op (singleton null span, zero events, zero state growth) so the
     serving hot loop can stay instrumented unconditionally.
   * ``MetricsRegistry`` — named counters / gauges / histograms with a

@@ -17,16 +17,29 @@ Clock: ``time.perf_counter_ns`` relative to the tracer's construction, so
 system); ``tid`` is a small dense alias of the Python thread ident, assigned
 in first-use order so the main thread is track 0.
 
+Profiler mirroring: an enabled span also enters a
+``jax.profiler.TraceAnnotation`` of the same name (the args it holds at
+entry become the annotation's stats), so a JAX profiler trace taken
+meanwhile holds the span in its ``.xplane.pb`` on the clock the device
+operations use.  The profiler's host clock is the wall clock
+(``time.time_ns``); the exporter writes the tracer's epoch on it as
+``profilerEpochNs``, so a span's ``ts * 1000 + profilerEpochNs`` is its
+start on that clock (an xplane's event starts are offsets from its
+``profile_start_time``).
+
 Disabled mode is the contract the serving hot loop relies on: ``span()``
 returns a module-level singleton null context (no allocation), ``instant``/
-``counter`` return before touching any state, and nothing is ever appended —
-``tests/test_obs.py`` pins all three properties with a counting probe.
+``counter`` return before touching any state, nothing is ever appended and
+no profiler annotation is created — ``tests/test_obs.py`` pins these
+properties with counting probes.
 """
 from __future__ import annotations
 
 import json
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 
 class _NullSpan:
@@ -44,8 +57,9 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Live span: times its ``with`` body and records one complete event."""
-    __slots__ = ("_tr", "name", "cat", "args", "_t0")
+    """Live span: times its ``with`` body, records one complete event, and
+    mirrors the body into the profiler as an annotation of the same name."""
+    __slots__ = ("_tr", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str, args):
         self._tr = tr
@@ -53,15 +67,21 @@ class _Span:
         self.cat = cat
         self.args = args           # caller may still mutate before __exit__
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self):
+        # the args known at entry become the annotation's stats
+        self._ann = TraceAnnotation(self.name, **(self.args or {}))
+        self._ann.__enter__()
         self._t0 = self._tr._now()
         return self
 
     def __exit__(self, *exc):
         tr = self._tr
+        end = tr._now()
+        self._ann.__exit__(*exc)
         tr._append({"name": self.name, "cat": self.cat, "ph": "X",
-                    "ts": self._t0, "dur": tr._now() - self._t0,
+                    "ts": self._t0, "dur": end - self._t0,
                     "pid": 0, "tid": tr._tid(),
                     "args": self.args if self.args is not None else {}})
         return False
@@ -75,6 +95,8 @@ class Tracer:
         self._events: list[dict] = []  # guarded-by: _lock
         self._lock = threading.Lock()
         self._epoch_ns = time.perf_counter_ns()
+        self._epoch_wall_ns = time.time_ns()    # the same instant, on the
+        #                                         profiler's host clock
         self._tids: dict[int, int] = {}  # guarded-by: _lock
 
     # -- clock / identity ---------------------------------------------------
@@ -113,8 +135,9 @@ class Tracer:
 
     # -- emission -----------------------------------------------------------
     def span(self, name: str, cat: str = "repro", args: dict | None = None):
-        """Context manager timing its body as one complete event.  Disabled:
-        returns the singleton ``NULL_SPAN`` — no allocation, no event."""
+        """Context manager timing its body as one complete event, mirrored
+        into the JAX profiler's trace.  Disabled: returns the singleton
+        ``NULL_SPAN`` — no allocation, no event, no annotation."""
         if not self.enabled:
             return NULL_SPAN
         return _Span(self, name, cat, args)
@@ -140,9 +163,11 @@ class Tracer:
     # -- export -------------------------------------------------------------
     def to_chrome(self) -> dict:
         """Chrome-trace JSON object: events sorted by ``ts`` (monotone), as
-        chrome://tracing and https://ui.perfetto.dev both ingest."""
+        chrome://tracing and https://ui.perfetto.dev both ingest, plus
+        ``profilerEpochNs``: where ``ts`` 0 lies on the profiler's clock."""
         evs = sorted(self.events, key=lambda e: e["ts"])
-        return {"traceEvents": evs, "displayTimeUnit": "ms"}
+        return {"traceEvents": evs, "displayTimeUnit": "ms",
+                "profilerEpochNs": self._epoch_wall_ns}
 
     def export(self, path: str) -> str:
         """Write the Chrome-trace JSON to ``path`` and return ``path``."""

@@ -97,7 +97,7 @@ from repro.configs.base import ModelConfig
 from repro.core.rollout import (RolloutResult, request_stream, sample_tokens,
                                 sampled_drawer)
 from repro.models.model import build_model
-from repro.obs import MetricsRegistry, get_tracer
+from repro.obs import NULL_SPAN, MetricsRegistry, get_tracer
 from repro.serve.host_tier import HostKVTier, SwapWorkerError
 from repro.serve.paged_cache import (PagedKVCache, blocks_for,
                                      scatter_prefill, scatter_token)
@@ -199,7 +199,10 @@ class ServingEngine:
         #   batched prefill — a hit there elides pool writes/blocks, not
         #   FLOPs); serve.shared_prefill_tokens = rows satisfied by a prefix
         #   match instead of a fresh prefill (compute savings on the online
-        #   path, block/memory savings on the batch path)
+        #   path, block/memory savings on the batch path); serve.launches =
+        #   calls of the engine's jitted programs and of request_stream,
+        #   serve.host_reads = device values read to the host, both counted
+        #   at their call sites
         self.tracer = tracer if tracer is not None else get_tracer()
         self.metrics = MetricsRegistry()
         # the host tier outlives pool regrows (_ensure_state rebuilds the
@@ -313,6 +316,8 @@ class ServingEngine:
             "shared_prefill_tokens": m.value("serve.shared_prefill_tokens"),
             "readmit_prefill_tokens": m.value("serve.readmit_prefill_tokens"),
             "decode_tokens": m.value("serve.decode_tokens"),
+            "launches": m.value("serve.launches"),
+            "host_reads": m.value("serve.host_reads"),
             "sampled_requests": m.value("serve.sampled.requests"),
             "sampled_tokens": m.value("serve.sampled.tokens"),
             "priority_bypass": m.value("serve.priority.bypass"),
@@ -439,8 +444,13 @@ class ServingEngine:
             seed = rid
         # greedy decoding never consumes a key — skip the stream derivation
         # so the greedy hot path stays dispatch-free at submit
-        stream = (None if self.greedy else
-                  np.asarray(request_stream(self._run_key, seed), np.uint32))
+        stream = None
+        if not self.greedy:
+            with self.tracer.span("serve.submit", cat="serve"):
+                stream = np.asarray(request_stream(self._run_key, seed),
+                                    np.uint32)
+            self.metrics.inc("serve.launches")
+            self.metrics.inc("serve.host_reads")
         # seeded tokens carry no engine-side logp (they were sampled in an
         # earlier run, possibly under different weights) — pad with zeros to
         # keep generated/gen_logp aligned
@@ -461,15 +471,25 @@ class ServingEngine:
         (mid-sequence seed included) — bitwise the draw the decode step
         would make for this request at the same logits, so admission-time
         first-token sampling and decode sampling are one stream arithmetic.
-        Greedy requests use the engine's fused greedy sampler."""
-        if req.stream is None:
-            t0, l0 = self._sample(logits)
-        else:
-            t0, l0 = self._draw(
-                logits, jnp.asarray(req.stream)[None],
-                jnp.full((1,), len(req.generated), jnp.int32),
-                jnp.zeros((1,), bool))
-        return int(t0[0]), float(l0[0])
+        Greedy requests use the engine's fused greedy sampler.  The reads
+        wait on the admission prefill: the host blocks here."""
+        with self.tracer.span("serve.first_token", cat="serve"):
+            if req.stream is None:
+                t0, l0 = self._sample(logits)
+            else:
+                t0, l0 = self._draw(
+                    logits, jnp.asarray(req.stream)[None],
+                    jnp.full((1,), len(req.generated), jnp.int32),
+                    jnp.zeros((1,), bool))
+            self.metrics.inc("serve.launches")
+            self.metrics.inc("serve.host_reads", 2)
+            return int(t0[0]), float(l0[0])
+
+    def _prefill_span(self, req: Request):
+        """The ``serve.prefill`` span of one admission prefill or chunk."""
+        tr = self.tracer
+        return (tr.span("serve.prefill", cat="serve", args={"rid": req.rid})
+                if tr.enabled else NULL_SPAN)
 
     def flush_prefix(self) -> None:
         """Drop every cached prefix now — BOTH tiers (the host tier flushes
@@ -502,8 +522,10 @@ class ServingEngine:
         long prompt never monopolizes a step.
 
         When the tracer is enabled, every step emits one ``serve.step`` span
-        plus ``serve.tokens`` / ``serve.slots`` counter samples; disabled,
-        this wrapper is a single predicate check on top of the hot loop."""
+        (its phases are child spans: ``serve.admit``, ``serve.decode.*``,
+        ``serve.retire``) plus ``serve.tokens`` / ``serve.slots`` counter
+        samples; disabled, this wrapper is a single predicate check on top
+        of the hot loop and each phase span is the null span."""
         tr = self.tracer
         if not tr.enabled:
             return self._step_once(params)
@@ -523,9 +545,8 @@ class ServingEngine:
         tr.counter("serve.slots",
                    {"running": self.sched.num_running if self.sched else 0,
                     "waiting": self.sched.num_pending if self.sched else 0,
-                    "preemptions": m.value("serve.preemptions"),
-                    "prefix_hit_rows": m.value(
-                        "serve.shared_prefill_tokens")}, cat="serve")
+                    "preemptions": m.value("serve.preemptions")},
+                   cat="serve")
         if self.host_tier is not None:
             tr.counter("serve.swap",
                        {"out_bytes": m.value("serve.swap.out_bytes"),
@@ -548,8 +569,10 @@ class ServingEngine:
             if self._seen_params is not None:
                 self.sched.flush_prefix()
             self._seen_params = params
+        tr = self.tracer
         self._step_prefill = 0
-        self._admit(params, finished)
+        with tr.span("serve.admit", cat="serve"):
+            self._admit(params, finished)
         self._advance_prefills(params, finished)
         self.metrics.set_max("serve.max_step_prefill", self._step_prefill)
         preempted = self.sched.ensure_capacity()
@@ -563,64 +586,70 @@ class ServingEngine:
             _ = self.cache.pool_k
             if self.cache.degraded:
                 self._handle_degradation()
-        decodable = [slot for slot, req in self.sched.running.items()
-                     if not self._prefilling(req)]
-        if not decodable:
-            return finished
-        s = self.max_slots
-        tok = np.full((s, 1), self.pad_id, np.int32)
-        pos = np.zeros((s,), np.int32)
-        done = np.ones((s,), bool)
-        streams = np.zeros((s, 2), np.uint32)   # idle/greedy: inert zero key
-        tcount = np.zeros((s,), np.int32)
-        tables = self.sched.tables
-        for slot, req in self.sched.running.items():
-            if self._prefilling(req):
-                # not decoding this step: route its KV write to the null
-                # block (a real table row would let the pad-token write
-                # clobber row 0 — possibly a SHARED prefix block)
-                tables = tables.copy() if tables is self.sched.tables \
-                    else tables
-                tables[slot, :] = self.cache.null_block
-                continue
-            tok[slot, 0] = req.generated[-1]
-            pos[slot] = req.cache_len
-            done[slot] = False
-            if req.stream is not None:
-                streams[slot] = req.stream
-                tcount[slot] = len(req.generated)
-        out = self._step(
-            params, self.cache.pool_k, self.cache.pool_v,
-            jnp.asarray(tables), jnp.asarray(tok),
-            jnp.asarray(pos), jnp.asarray(done))
-        if self.greedy:
-            pool_k, pool_v, nxt, lp = out
-        else:
-            pool_k, pool_v, logits = out
-            nxt, lp = self._draw(logits, jnp.asarray(streams),
-                                 jnp.asarray(tcount), jnp.asarray(done))
+        with tr.span("serve.decode.prep", cat="serve"):
+            decodable = [slot for slot, req in self.sched.running.items()
+                         if not self._prefilling(req)]
+            if not decodable:
+                return finished
+            s = self.max_slots
+            tok = np.full((s, 1), self.pad_id, np.int32)
+            pos = np.zeros((s,), np.int32)
+            done = np.ones((s,), bool)
+            streams = np.zeros((s, 2), np.uint32)  # idle/greedy: zero key
+            tcount = np.zeros((s,), np.int32)
+            tables = self.sched.tables
+            for slot, req in self.sched.running.items():
+                if self._prefilling(req):
+                    # not decoding this step: route its KV write to the null
+                    # block (a real table row would let the pad-token write
+                    # clobber row 0 — possibly a SHARED prefix block)
+                    tables = tables.copy() if tables is self.sched.tables \
+                        else tables
+                    tables[slot, :] = self.cache.null_block
+                    continue
+                tok[slot, 0] = req.generated[-1]
+                pos[slot] = req.cache_len
+                done[slot] = False
+                if req.stream is not None:
+                    streams[slot] = req.stream
+                    tcount[slot] = len(req.generated)
+        with tr.span("serve.decode.launch", cat="serve"):
+            out = self._step(
+                params, self.cache.pool_k, self.cache.pool_v,
+                jnp.asarray(tables), jnp.asarray(tok),
+                jnp.asarray(pos), jnp.asarray(done))
+            if self.greedy:
+                pool_k, pool_v, nxt, lp = out
+            else:
+                pool_k, pool_v, logits = out
+                nxt, lp = self._draw(logits, jnp.asarray(streams),
+                                     jnp.asarray(tcount), jnp.asarray(done))
+        self.metrics.inc("serve.launches", 1 if self.greedy else 2)
         self.cache.pool_k, self.cache.pool_v = pool_k, pool_v
         self.metrics.inc("serve.steps")
         self.metrics.inc("serve.decode_tokens", len(decodable))
         if not self.greedy:
             self.metrics.inc("serve.sampled.tokens", len(decodable))
-        nxt = np.asarray(nxt)
-        lp = np.asarray(lp)
-        for slot in decodable:
-            req = self.sched.running[slot]
-            # the row just written lives in this block: taint it against
-            # host spill (decode bytes are not prefill-reproducible)
-            self.cache.mark_decode_write(int(
-                self.sched.tables[slot, req.cache_len // self.block_size]))
-            req.cache_len += 1
-            req.generated.append(int(nxt[slot]))
-            req.gen_logp.append(float(lp[slot]))
-            if req.cache_len % self.block_size == 0:
-                # a decode-filled block just completed: index it so a
-                # budget-suspended resume (or identical sampled prefix)
-                # re-matches instead of re-prefilling
-                self.sched.register_prefix(req)
-            self._retire(req, finished)
+        with tr.span("serve.decode.wait", cat="serve"):
+            nxt = np.asarray(nxt)
+            lp = np.asarray(lp)
+        self.metrics.inc("serve.host_reads", 2)
+        with tr.span("serve.retire", cat="serve"):
+            for slot in decodable:
+                req = self.sched.running[slot]
+                # the row just written lives in this block: taint it against
+                # host spill (decode bytes are not prefill-reproducible)
+                self.cache.mark_decode_write(int(self.sched.tables[
+                    slot, req.cache_len // self.block_size]))
+                req.cache_len += 1
+                req.generated.append(int(nxt[slot]))
+                req.gen_logp.append(float(lp[slot]))
+                if req.cache_len % self.block_size == 0:
+                    # a decode-filled block just completed: index it so a
+                    # budget-suspended resume (or identical sampled prefix)
+                    # re-matches instead of re-prefilling
+                    self.sched.register_prefix(req)
+                self._retire(req, finished)
         return finished
 
     def _handle_degradation(self) -> None:
@@ -721,9 +750,13 @@ class ServingEngine:
                 req.stash = None
                 p = krows.shape[1]
                 self.metrics.inc("serve.prefill_tokens", p)
-                flat = self._write_rows(req.slot, 0, matched, p, p)
-                self.cache.pool_k = self._write(self.cache.pool_k, krows, flat)
-                self.cache.pool_v = self._write(self.cache.pool_v, vrows, flat)
+                with self._prefill_span(req):
+                    flat = self._write_rows(req.slot, 0, matched, p, p)
+                    self.cache.pool_k = self._write(self.cache.pool_k, krows,
+                                                    flat)
+                    self.cache.pool_v = self._write(self.cache.pool_v, vrows,
+                                                    flat)
+                self.metrics.inc("serve.launches", 2)
                 req.cache_len = p
                 self.sched.register_prefix(req)
                 self._first_token(req, tok0, lp0, finished)
@@ -739,10 +772,17 @@ class ServingEngine:
                 pb = prefill_bucket(p)
                 padded = np.full((pb,), self.pad_id, np.int32)
                 padded[:p] = toks
-                logits, cache = self._prefill(
-                    params, {"tokens": jnp.asarray(padded[None])},
-                    jnp.int32(p - 1))
-                krows, vrows = cache["k"][:, 0], cache["v"][:, 0]
+                with self._prefill_span(req):
+                    logits, cache = self._prefill(
+                        params, {"tokens": jnp.asarray(padded[None])},
+                        jnp.int32(p - 1))
+                    krows, vrows = cache["k"][:, 0], cache["v"][:, 0]
+                    flat = self._write_rows(req.slot, 0, 0, p, pb)
+                    self.cache.pool_k = self._write(self.cache.pool_k, krows,
+                                                    flat)
+                    self.cache.pool_v = self._write(self.cache.pool_v, vrows,
+                                                    flat)
+                self.metrics.inc("serve.launches", 3)
                 self.metrics.inc("serve.prefill_tokens", p)
                 if req.preemptions:
                     # re-admission prefill: with a host tier most of these
@@ -750,9 +790,6 @@ class ServingEngine:
                     # machine-readable recompute-vs-swap A/B quantity
                     self.metrics.inc("serve.readmit_prefill_tokens", p)
                 self._step_prefill += p
-                flat = self._write_rows(req.slot, 0, 0, p, pb)
-                self.cache.pool_k = self._write(self.cache.pool_k, krows, flat)
-                self.cache.pool_v = self._write(self.cache.pool_v, vrows, flat)
                 req.cache_len = p
                 self.sched.register_prefix(req)
                 t0, l0 = self._first_sample(logits, req)
@@ -808,13 +845,16 @@ class ServingEngine:
         cb = prefill_bucket(take)
         chunk = np.full((cb,), self.pad_id, np.int32)
         chunk[:take] = toks[start:start + take]
-        logits, krows, vrows = self._chunk(
-            params, pool_k, pool_v,
-            jnp.asarray(self.sched.tables[req.slot]),
-            jnp.asarray(chunk[None]), jnp.int32(start), jnp.int32(take - 1))
-        flat = self._write_rows(req.slot, start, 0, take, cb)
-        self.cache.pool_k = self._write(self.cache.pool_k, krows, flat)
-        self.cache.pool_v = self._write(self.cache.pool_v, vrows, flat)
+        with self._prefill_span(req):
+            logits, krows, vrows = self._chunk(
+                params, pool_k, pool_v,
+                jnp.asarray(self.sched.tables[req.slot]),
+                jnp.asarray(chunk[None]), jnp.int32(start),
+                jnp.int32(take - 1))
+            flat = self._write_rows(req.slot, start, 0, take, cb)
+            self.cache.pool_k = self._write(self.cache.pool_k, krows, flat)
+            self.cache.pool_v = self._write(self.cache.pool_v, vrows, flat)
+        self.metrics.inc("serve.launches", 3)
         req.cache_len = start + take
         self.metrics.inc("serve.prefill_tokens", take)
         if req.preemptions:
@@ -918,6 +958,8 @@ class ServingEngine:
                                    jnp.zeros((b,), jnp.int32),
                                    jnp.zeros((b,), bool))
         tok0, lp0 = np.asarray(tok0), np.asarray(lp0)
+        self.metrics.inc("serve.launches", 3)    # prefill, streams, draw
+        self.metrics.inc("serve.host_reads", 3)  # streams, tok0, lp0
 
         rows: dict[int, tuple] = {}
 

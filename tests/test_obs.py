@@ -1,10 +1,12 @@
 """Telemetry layer (repro.obs): tracer, registry, and the instrumented
 serve/graph/dock layers — including the disabled-mode overhead contract
 and greedy bit-identity with tracing ON."""
+import glob
 import json
 import subprocess
 import sys
 import threading
+import warnings
 from pathlib import Path
 
 import jax
@@ -22,6 +24,7 @@ from repro.data.prompts import PromptDataset, pattern_task
 from repro.data.tokenizer import ByteTokenizer
 from repro.models.model import build_model
 from repro.obs import NULL_SPAN, MetricsRegistry, Tracer, get_tracer
+from repro.obs import tracer as tracer_mod
 from repro.serve.engine import ServingEngine
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -41,6 +44,20 @@ class CountingTracer(Tracer):
     def _append(self, ev):
         self.appends += 1
         super()._append(ev)
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """Probe: counts the profiler annotations the tracer creates."""
+    made = []
+
+    class Counting(tracer_mod.TraceAnnotation):
+        def __init__(self, name, **kw):
+            made.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", Counting)
+    return made
 
 
 @pytest.fixture(scope="module")
@@ -127,7 +144,8 @@ def test_exporter_chrome_schema(tmp_path):
     tr.counter("cnt", {"a": 1, "b": 2})
     path = tr.export(str(tmp_path / "t.trace.json"))
     doc = json.load(open(path))
-    assert set(doc) == {"traceEvents", "displayTimeUnit"}
+    assert set(doc) == {"traceEvents", "displayTimeUnit", "profilerEpochNs"}
+    assert doc["profilerEpochNs"] == tr._epoch_wall_ns > 0
     evs = doc["traceEvents"]
     assert len(evs) == 3
     for ev in evs:
@@ -292,22 +310,133 @@ def test_generate_bitcompat_with_tracer_enabled(setup):
     assert any(e["name"] == "serve.step" for e in tr.events)
 
 
-def test_disabled_tracer_adds_nothing_to_serving_steps(setup):
+@pytest.mark.parametrize("greedy", [True, False])
+def test_disabled_tracer_adds_nothing_to_serving_steps(setup, annotations,
+                                                       greedy):
     """Overhead guard: a full serving run with the tracer disabled must
-    append ZERO events and allocate ZERO span objects (every span() call
-    returns the module singleton) — counter-based, immune to CPU noise."""
+    append ZERO events, allocate ZERO span objects (every span() call
+    returns the module singleton) and create ZERO profiler annotations —
+    counter-based, immune to CPU noise."""
     cfg, _, params = setup
     tr = CountingTracer(enabled=False)
     eng = ServingEngine(cfg, max_new=6, eos_id=TOK.eos_id, pad_id=TOK.pad_id,
-                        greedy=True, max_slots=2, block_size=4, tracer=tr)
+                        greedy=greedy, max_slots=2, block_size=4, tracer=tr)
     for p in _prompts(3, 8, seed=4):
         eng.submit(p)
     outs = eng.drain(params)
     assert len(outs) == 3
     assert tr.appends == 0 and tr.events == []
+    assert annotations == []
     assert eng.tracer.span("probe") is NULL_SPAN
     # the registry keeps counting regardless — stats() is always available
     assert eng.stats()["finished"] == 3
+
+
+def test_enabled_tracer_creates_one_annotation_per_span(setup, annotations):
+    """The probe above sees annotations when there are some: each span of
+    an enabled tracer creates exactly one, of its own name."""
+    cfg, _, params = setup
+    tr = Tracer(enabled=True)
+    eng = ServingEngine(cfg, max_new=4, eos_id=TOK.eos_id, pad_id=TOK.pad_id,
+                        greedy=False, max_slots=2, block_size=4, tracer=tr)
+    for p in _prompts(2, 8, seed=6):
+        eng.submit(p)
+    eng.drain(params)
+    spans = [e["name"] for e in tr.events if e["ph"] == "X"]
+    assert sorted(annotations) == sorted(spans)
+    assert {"serve.submit", "serve.step", "serve.admit", "serve.prefill",
+            "serve.first_token", "serve.decode.prep", "serve.decode.launch",
+            "serve.decode.wait", "serve.retire"} == set(spans)
+
+
+def _xplane_spans(trace_dir):
+    """(name, start on the profiler's clock, end, stats) of every host
+    event of the trace, and the trace's start on that clock."""
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))
+    assert path, "the profiler wrote no trace"
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", DeprecationWarning)
+        pd = ProfileData.from_file(path[-1])
+        planes = list(pd.planes)
+        t0 = [dict(p.stats)["profile_start_time"] for p in planes
+              if p.name == "Task Environment"]
+        assert len(t0) == 1
+        out = []
+        for plane in planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    for ev in line.events:
+                        out.append((ev.name, ev.start_ns + t0[0],
+                                    ev.start_ns + ev.duration_ns + t0[0],
+                                    dict(ev.stats)))
+    return out
+
+
+def test_enabled_spans_reach_the_profiler_trace(setup, tmp_path):
+    """An enabled tracer's spans land in a JAX profiler trace (CPU here),
+    nested as they ran — ``serve.decode.wait`` inside ``serve.step`` — with
+    the prefill's ``rid`` as a stat, and the Chrome export's
+    ``profilerEpochNs`` puts each span within 1 ms of its xplane start."""
+    cfg, _, params = setup
+    tr = Tracer(enabled=True)
+    eng = ServingEngine(cfg, max_new=4, eos_id=TOK.eos_id, pad_id=TOK.pad_id,
+                        greedy=False, max_slots=2, block_size=4, tracer=tr)
+    eng.submit(_prompts(1, 8, seed=7)[0])
+    eng.drain(params)                  # compiles outside the trace
+    tr.clear()
+    s0 = eng.steps
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for p in _prompts(2, 8, seed=8):
+            eng.submit(p)
+        eng.drain(params)
+    finally:
+        jax.profiler.stop_trace()
+    xs = [x for x in _xplane_spans(tmp_path) if x[0].startswith("serve.")]
+    steps = [x for x in xs if x[0] == "serve.step"]
+    waits = [x for x in xs if x[0] == "serve.decode.wait"]
+    assert len(steps) == eng.steps - s0 and len(waits) == len(steps)
+    for _, s, e, _ in waits:
+        assert any(s0 <= s and e <= e0 for _, s0, e0, _ in steps)
+    rids = sorted(x[3]["rid"] for x in xs if x[0] == "serve.prefill")
+    assert rids == [1, 2]
+    doc = tr.to_chrome()
+    chrome = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    assert sorted(e["name"] for e in chrome) == sorted(x[0] for x in xs)
+    starts = {}
+    for name, s, _, _ in xs:
+        starts.setdefault(name, []).append(s)
+    for e in chrome:
+        at = e["ts"] * 1e3 + doc["profilerEpochNs"]
+        assert min(abs(at - s) for s in starts[e["name"]]) < 1e6
+
+
+@pytest.mark.parametrize("greedy", [True, False])
+def test_launch_and_host_read_counts(setup, greedy):
+    """Exact ``serve.launches`` / ``serve.host_reads`` of a run of known
+    shape: n distinct prompts admitted in the first step, every request
+    running to ``max_new`` (the EOS id lies outside the vocabulary).  Per
+    submit (sampled): the stream, 1 launch + 1 read; per admission: the
+    prefill, two pool writes and the first-token draw, 4 launches + 2
+    reads; per decode step: ``_step`` (+ ``_draw`` sampled) and 2 reads."""
+    cfg, _, params = setup
+    n, mn = 3, 5
+    eng = ServingEngine(cfg, max_new=mn, eos_id=cfg.vocab_size + 7,
+                        pad_id=TOK.pad_id, greedy=greedy, max_slots=n,
+                        block_size=4)
+    for p in _prompts(n, 8, seed=9):
+        eng.submit(p)
+    outs = eng.drain(params)
+    assert sorted(len(o.gen) for o in outs) == [mn] * n
+    st = eng.stats()
+    steps = mn - 1
+    assert st["steps"] == steps and st["prefill_tokens"] == 8 * n
+    per_submit = 0 if greedy else 1
+    per_step = 1 if greedy else 2
+    assert st["launches"] == n * (per_submit + 4) + steps * per_step
+    assert st["host_reads"] == n * (per_submit + 2) + steps * 2
 
 
 # ---------------------------------------------------------------------------
