@@ -461,33 +461,37 @@ def chunk_prefill_attention(q, k_new, v_new, pool_k, pool_v, table, start, *,
     return out
 
 
-def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, tables, pos, *,
-                           block_size: int, window: int = 0,
+def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, layer, tables,
+                           pos, *, block_size: int, window: int = 0,
                            scale: float | None = None) -> jnp.ndarray:
     """One-token attention straight off the paged KV pool — the serving hot
     loop's attention (no dense per-slot gather is ever materialized).
 
     q: (S, 1, H, d) decode queries; k_new/v_new: (S, KV, d) the in-flight
     token's KV (scattered into the pool by the caller AFTER this);
-    pool_k/pool_v: (R, KV, d) one layer's row pool; tables: (S, MB) int32;
+    pool_k/pool_v: (n, R, KV, d) the stacked row pools of all layers, as
+    stored; layer: () int32 the layer to read; tables: (S, MB) int32;
     pos: (S,) int32 cached rows per slot.
 
     Dispatch: flash-decoding Pallas kernel on TPU (or REPRO_PALLAS=interpret),
-    else the chunked two-pass jnp reference — which is BITWISE equal to
-    ``decode_attention`` over the dense-gathered view, preserving the serving
-    engine's bit-compatibility with the synchronized rollout engine.
+    which reads the layer's live pages out of the stacked pool itself; else
+    the chunked two-pass jnp reference on ``pool[layer]`` — which is BITWISE
+    equal to ``decode_attention`` over the dense-gathered view, preserving
+    the serving engine's bit-compatibility with the synchronized rollout
+    engine.
     """
     if _use_pallas():
         from repro.kernels import paged_attention as _k
 
         out = _k.paged_decode_attention(
-            q[None, :, 0], k_new[None], v_new[None], pool_k[None],
-            pool_v[None], tables, pos, block_size=block_size, window=window,
-            scale=scale, interpret=_interpret())
-        return out[0][:, None]
-    return ref.paged_decode_attention(q, k_new, v_new, pool_k, pool_v, tables,
-                                      pos, block_size=block_size,
-                                      window=window, scale=scale)
+            q[:, 0], k_new, v_new, pool_k, pool_v, layer, tables, pos,
+            block_size=block_size, window=window, scale=scale,
+            interpret=_interpret())
+        return out[:, None]
+    return ref.paged_decode_attention(q, k_new, v_new, pool_k[layer],
+                                      pool_v[layer], tables, pos,
+                                      block_size=block_size, window=window,
+                                      scale=scale)
 
 
 # ---------------------------------------------------------------------------

@@ -135,20 +135,21 @@ def attn_decode(p: dict, cfg: ModelConfig, x1: jnp.ndarray, cos1, sin1,
 
 
 def attn_decode_paged(p: dict, cfg: ModelConfig, x1: jnp.ndarray, cos1, sin1,
-                      pool_k, pool_v, tables, pos, block_size: int,
+                      pool_k, pool_v, layer, tables, pos, block_size: int,
                       window: int):
     """One-token decode against the PAGED pool (continuous-batching serving).
 
-    pool_k/pool_v: one layer's row pool (R, KV, hd) — read-only here; no
-    dense per-slot cache view is ever built.  tables: (S, MB) int32 block
-    table; pos: (S,) int32 cached rows per slot.  Returns the attention
-    output plus this token's (k, v) rows (S, KV, hd) for the engine to
-    scatter into the pool after the step."""
+    pool_k/pool_v: the stacked row pools of all layers (n, R, KV, hd) —
+    read-only here; ``layer`` () int32 picks this layer's; no dense
+    per-slot cache view is ever built.  tables: (S, MB) int32 block table;
+    pos: (S,) int32 cached rows per slot.  Returns the attention output plus
+    this token's (k, v) rows (S, KV, hd) for the engine to scatter into the
+    pool after the step."""
     q, k, v = _qkv(p, cfg, x1)
     if cos1 is not None:
         q = ops.apply_rope(q, cos1[:, :, None, :], sin1[:, :, None, :])
         k = ops.apply_rope(k, cos1[:, :, None, :], sin1[:, :, None, :])
-    y = ops.paged_decode_attention(q, k[:, 0], v[:, 0], pool_k, pool_v,
+    y = ops.paged_decode_attention(q, k[:, 0], v[:, 0], pool_k, pool_v, layer,
                                    tables, pos, block_size=block_size,
                                    window=window)
     return attn_project_out(p, y), k[:, 0], v[:, 0]

@@ -273,17 +273,18 @@ def decode_paged(params: dict, cfg: ModelConfig, pool_k: jnp.ndarray,
     cos, sin = L.rope_for(cfg, T._positions(cfg, b, 1, offset=pos[:, None]))
 
     def body(h, xs):
-        lp, pk, pv = xs
+        lp, layer = xs
         y, k1, v1 = L.attn_decode_paged(lp["attn"], cfg,
                                         L.norm_apply(lp["ln1"], cfg, h),
-                                        cos, sin, pk, pv, tables, pos,
-                                        block_size, window)
+                                        cos, sin, pool_k, pool_v, layer,
+                                        tables, pos, block_size, window)
         h = h + y
         h = h + moe_decode_apply(lp["moe"], cfg,
                                  L.norm_apply(lp["ln2"], cfg, h))
         return h, (k1, v1)
 
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], pool_k, pool_v))
+    layers = jnp.arange(pool_k.shape[0], dtype=jnp.int32)
+    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], layers))
     x = L.norm_apply(params["ln_f"], cfg, x)
     logits = L.unembed(params, cfg, x)[:, 0].astype(jnp.float32)
     return logits, ks, vs

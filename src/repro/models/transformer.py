@@ -220,7 +220,9 @@ def decode_paged(params: dict, cfg: ModelConfig, pool_k: jnp.ndarray,
                  tokens: jnp.ndarray, pos: jnp.ndarray, *, block_size: int):
     """One decode step against the PAGED KV pool (continuous-batching
     serving).  tokens: (S, 1); pos: (S,) int32 per-slot cached rows;
-    pool_k/pool_v: (n, R, kv, hd) row pools; tables: (S, MB) int32.
+    pool_k/pool_v: (n, R, kv, hd) row pools; tables: (S, MB) int32.  The
+    layer scan carries a layer index, not pool slices: each layer's
+    attention takes the stacked pools whole and reads its own pages.
 
     Returns (logits, new_k, new_v) where new_k/new_v (n, S, kv, hd) are this
     token's KV rows for the engine to scatter into the pool — the model
@@ -234,16 +236,17 @@ def decode_paged(params: dict, cfg: ModelConfig, pool_k: jnp.ndarray,
     cos, sin = _rope(cfg, _positions(cfg, b, 1, offset=pos[:, None]))
 
     def body(h, xs):
-        lp, pk, pv = xs
+        lp, layer = xs
         y, k1, v1 = L.attn_decode_paged(lp["attn"], cfg,
                                         L.norm_apply(lp["ln1"], cfg, h),
-                                        cos, sin, pk, pv, tables, pos,
-                                        block_size, window)
+                                        cos, sin, pool_k, pool_v, layer,
+                                        tables, pos, block_size, window)
         h = h + y
         h = h + L.mlp_apply(lp["mlp"], cfg, L.norm_apply(lp["ln2"], cfg, h))
         return h, (k1, v1)
 
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], pool_k, pool_v))
+    layers = jnp.arange(pool_k.shape[0], dtype=jnp.int32)
+    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], layers))
     x = L.norm_apply(params["ln_f"], cfg, x)
     logits = L.unembed(params, cfg, x)[:, 0].astype(jnp.float32)
     return logits, ks, vs

@@ -96,7 +96,9 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.rollout import (RolloutResult, request_stream, sample_tokens,
                                 sampled_drawer)
+from repro.kernels.paged_attention import page_span
 from repro.models.model import build_model
+from repro.models.transformer import paged_window
 from repro.obs import NULL_SPAN, MetricsRegistry, get_tracer
 from repro.serve.host_tier import HostKVTier, SwapWorkerError
 from repro.serve.paged_cache import (PagedKVCache, blocks_for,
@@ -202,7 +204,9 @@ class ServingEngine:
         #   path, block/memory savings on the batch path); serve.launches =
         #   calls of the engine's jitted programs and of request_stream,
         #   serve.host_reads = device values read to the host, both counted
-        #   at their call sites
+        #   at their call sites; serve.decode.kv_pages = pool pages the
+        #   paged decode kernel walks for ONE layer, summed over decode
+        #   steps (from the host positions, no device read)
         self.tracer = tracer if tracer is not None else get_tracer()
         self.metrics = MetricsRegistry()
         # the host tier outlives pool regrows (_ensure_state rebuilds the
@@ -316,6 +320,7 @@ class ServingEngine:
             "shared_prefill_tokens": m.value("serve.shared_prefill_tokens"),
             "readmit_prefill_tokens": m.value("serve.readmit_prefill_tokens"),
             "decode_tokens": m.value("serve.decode_tokens"),
+            "decode_kv_pages": m.value("serve.decode.kv_pages"),
             "launches": m.value("serve.launches"),
             "host_reads": m.value("serve.host_reads"),
             "sampled_requests": m.value("serve.sampled.requests"),
@@ -613,6 +618,10 @@ class ServingEngine:
                 if req.stream is not None:
                     streams[slot] = req.stream
                     tcount[slot] = len(req.generated)
+            first, end = page_span(
+                pos, self.block_size,
+                paged_window(self.cfg, tables.shape[1] * self.block_size))
+            self.metrics.inc("serve.decode.kv_pages", int((end - first).sum()))
         with tr.span("serve.decode.launch", cat="serve"):
             out = self._step(
                 params, self.cache.pool_k, self.cache.pool_v,

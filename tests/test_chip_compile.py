@@ -74,17 +74,89 @@ def _fwd_and_grad(fn, args, argnums):
     _compile(jax.value_and_grad(loss, argnums=argnums), *args)
 
 
+def _paged_decode(sh, *, slots, entries, block, layers, h, kv, hd=HD):
+    """Compile the paged decode kernel over a stacked ``layers``-layer pool
+    sized for ``slots`` sequences of ``entries`` table entries."""
+    rows = (slots * entries + 1) * block               # + the null block
+    return _compile(
+        lambda q, kn, vn, pk, pv, ly, t, p:
+        paged_attention.paged_decode_attention(
+            q, kn, vn, pk, pv, ly, t, p, block_size=block, interpret=False),
+        sh((slots, h, hd)), sh((slots, kv, hd)), sh((slots, kv, hd)),
+        sh((layers, rows, kv, hd)), sh((layers, rows, kv, hd)),
+        sh((), jnp.int32), sh((slots, entries), jnp.int32),
+        sh((slots,), jnp.int32))
+
+
 def test_paged_decode_attention_8_slots(one_chip):
-    slots, block, blocks_per_seq = 8, 16, 4
-    rows = (slots * blocks_per_seq + 1) * block       # + the null block
     sh = lambda shape, dt=jnp.bfloat16: _shape(one_chip, shape, dt)  # noqa: E731
-    compiled = _compile(
-        lambda q, kn, vn, pk, pv, t, p: paged_attention.paged_decode_attention(
-            q, kn, vn, pk, pv, t, p, block_size=block, interpret=False),
-        sh((1, slots, H, HD)), sh((1, slots, KV, HD)), sh((1, slots, KV, HD)),
-        sh((1, rows, KV, HD)), sh((1, rows, KV, HD)),
-        sh((slots, blocks_per_seq), jnp.int32), sh((slots,), jnp.int32))
+    compiled = _paged_decode(sh, slots=8, entries=4, block=16, layers=1,
+                             h=H, kv=KV)
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+@pytest.mark.parametrize("h,kv", [(H, KV), (28, 4)],
+                         ids=["yi6b_g8", "qwen25_7b_g7"])
+def test_paged_decode_attention_serving_shapes(one_chip, h, kv):
+    """The serving cell's shapes: 128 slots, 64 table entries of 16 rows,
+    the stacked 8-layer pool read in place (no copy of it feeds the
+    kernel); and Qwen2.5-7B's 7 query heads per KV head."""
+    sh = lambda shape, dt=jnp.bfloat16: _shape(one_chip, shape, dt)  # noqa: E731
+    compiled = _paged_decode(sh, slots=128, entries=64, block=16, layers=8,
+                             h=h, kv=kv)
+    text = compiled.as_text()
+    pool = "bf16[8,131088,4,128]"
+    made = [ln for ln in text.splitlines()
+            if f"= {pool}" in ln and " parameter(" not in ln]
+    assert not made, made
+
+
+def test_decode_layer_scan_reads_pool_in_place(one_chip, monkeypatch):
+    """An 8-layer ``decode_paged`` at yi-6b widths over the serving pool:
+    no op inside the layer scan's while body makes a pool-sized array —
+    neither a per-layer slice (``[..., 131088, 4, 128]``) nor the kernel's
+    old page relayout (``[..., 8193, 16, 512]``); the stacked pool only
+    passes through the loop to the kernel."""
+    import re
+
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models.model import build_model
+
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = get_config("yi-6b").replace(num_layers=8)
+    model = build_model(cfg)
+    shapes = jax.eval_shape(lambda k: model.init(cfg, k),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: _shape(one_chip, s.shape, s.dtype),
+                          shapes)
+    slots, entries, block = 128, 64, 16
+    pool = _shape(one_chip, (8, (slots * entries + 1) * block, KV, HD))
+    compiled = _compile(
+        lambda p, pk, pv, t, tok, pos: model.decode_paged(
+            p, cfg, pk, pv, t, tok, pos, block_size=block),
+        params, pool, pool, _shape(one_chip, (slots, entries), jnp.int32),
+        _shape(one_chip, (slots, 1), jnp.int32),
+        _shape(one_chip, (slots,), jnp.int32))
+    text = compiled.as_text()
+    bodies = set(re.findall(r"\bwhile\(.*?body=%?([\w.-]+)", text))
+    assert bodies, "no layer scan in the program"
+    comps = re.split(r"\n(?=\S)", text)
+    pool_shape = re.compile(r"=\s*bf16\[(?:\d+,)*(131088,4,128|8193,16,512)\]")
+    seen = 0
+    for comp in comps:
+        name = re.match(r"%?([\w.-]+)", comp)
+        if not name or name.group(1) not in bodies:
+            continue
+        seen += 1
+        for ln in comp.splitlines()[1:]:
+            if pool_shape.search(ln) and "get-tuple-element(" not in ln \
+                    and " parameter(" not in ln:
+                raise AssertionError(f"pool-sized op in the layer scan: "
+                                     f"{ln.strip()[:200]}")
+    assert seen == len(bodies)
+    assert "paged_decode_attention" in text
 
 
 def test_flash_attention_fwd_and_grad(one_chip):

@@ -24,7 +24,8 @@ from repro.configs import get_smoke_config
 from repro.core.rollout import RolloutEngine
 from repro.data.tokenizer import ByteTokenizer
 from repro.kernels import ops, ref
-from repro.kernels.paged_attention import paged_decode_attention as pallas_pda
+from repro.kernels.paged_attention import (page_span, pages_per_block,
+                                           paged_decode_attention as pallas_pda)
 from repro.models.model import build_model
 from repro.serve.engine import ServingEngine, prefill_bucket
 from repro.serve.paged_cache import gather_pool_ref
@@ -61,7 +62,7 @@ def _rand_case(seed, s=4, kv=2, g=4, hd=32, bs=4, mb=5, nblk=24):
     return q, k_new, v_new, pool_k, pool_v, tables, jnp.asarray(pos), bs
 
 
-def _oracle(q, k_new, v_new, pool_k, pool_v, tables, pos, bs):
+def _oracle(q, k_new, v_new, pool_k, pool_v, tables, pos, bs, window=0):
     """gather_kv + insert-at-pos + dense decode_attention (the old path)."""
     kc = gather_pool_ref(pool_k[None], tables, bs)[0]
     vc = gather_pool_ref(pool_v[None], tables, bs)[0]
@@ -70,7 +71,18 @@ def _oracle(q, k_new, v_new, pool_k, pool_v, tables, pos, bs):
     vc = vc.at[rows, pos].set(v_new)
     cap = tables.shape[1] * bs
     valid = jnp.arange(cap)[None, :] <= pos[:, None]
+    if window > 0:
+        valid &= jnp.arange(cap)[None, :] > pos[:, None] - window
     return ops.decode_attention(q, kc, vc, valid)
+
+
+def _pallas(q, k_new, v_new, pool_k, pool_v, tables, pos, bs, window=0):
+    """The Pallas kernel (TPU interpret mode) on one layer's pool, as
+    layer 0 of a one-layer stack; returns the oracle's (S, 1, H, d)."""
+    out = pallas_pda(q[:, 0], k_new, v_new, pool_k[None], pool_v[None],
+                     jnp.int32(0), tables, pos, block_size=bs, window=window,
+                     interpret=True)
+    return out[:, None]
 
 
 def test_ref_bitwise_matches_dense_oracle():
@@ -86,10 +98,8 @@ def test_pallas_interpret_close_to_oracle():
     q, k_new, v_new, pool_k, pool_v, tables, pos, bs = _rand_case(1)
     want = np.asarray(jax.jit(_oracle, static_argnums=(7,))(
         q, k_new, v_new, pool_k, pool_v, tables, pos, bs))
-    got = pallas_pda(q[None, :, 0], k_new[None], v_new[None], pool_k[None],
-                     pool_v[None], tables, pos, block_size=bs, interpret=True)
-    np.testing.assert_allclose(want, np.asarray(got[0][:, None]),
-                               rtol=2e-5, atol=2e-5)
+    got = _pallas(q, k_new, v_new, pool_k, pool_v, tables, pos, bs)
+    np.testing.assert_allclose(want, np.asarray(got), rtol=2e-5, atol=2e-5)
 
 
 def test_property_random_tables_ragged_pos():
@@ -105,10 +115,8 @@ def test_property_random_tables_ragged_pos():
             ref.paged_decode_attention,
             static_argnames=("block_size",))(*case[:-1], block_size=bs))
         np.testing.assert_array_equal(want, got, err_msg=f"seed {seed}")
-        pk = pallas_pda(q[None, :, 0], k_new[None], v_new[None],
-                        pool_k[None], pool_v[None], tables, pos,
-                        block_size=bs, interpret=True)
-        np.testing.assert_allclose(want, np.asarray(pk[0][:, None]),
+        pk = _pallas(q, k_new, v_new, pool_k, pool_v, tables, pos, bs)
+        np.testing.assert_allclose(want, np.asarray(pk),
                                    rtol=2e-5, atol=2e-5,
                                    err_msg=f"seed {seed}")
 
@@ -129,11 +137,72 @@ def test_ref_sliding_window_matches_oracle():
         ref.paged_decode_attention, static_argnames=("block_size", "window"))(
         q, k_new, v_new, pool_k, pool_v, tables, pos, block_size=bs, window=w))
     np.testing.assert_array_equal(want, got)
-    pk = pallas_pda(q[None, :, 0], k_new[None], v_new[None], pool_k[None],
-                    pool_v[None], tables, pos, block_size=bs, window=w,
-                    interpret=True)
-    np.testing.assert_allclose(want, np.asarray(pk[0][:, None]),
+    pk = _pallas(q, k_new, v_new, pool_k, pool_v, tables, pos, bs, window=w)
+    np.testing.assert_allclose(want, np.asarray(pk), rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the kernel's page walk (TPU interpret mode: DMAs and semaphores simulated)
+# ---------------------------------------------------------------------------
+
+# (kv, g, block_size, table width, layers, layer read, window).  At
+# block_size 4 a compute block is 32 pages, so a 40-entry table ends in a
+# partial block; windows of 9 and 13 rows skip whole pages.
+WALK_CASES = {
+    "g1_mha": (4, 1, 4, 6, 1, 0, 0),
+    "g4": (2, 4, 4, 5, 1, 0, 0),
+    "g7": (2, 7, 4, 6, 1, 0, 0),
+    "g8": (1, 8, 8, 4, 1, 0, 0),
+    "partial_last_block": (1, 4, 4, 40, 1, 0, 0),
+    "window_skips_pages": (2, 4, 4, 12, 1, 0, 9),
+    "window_partial_block": (1, 2, 2, 40, 1, 0, 13),
+    "layer_2_of_3": (2, 4, 4, 6, 3, 2, 0),
+}
+
+
+@pytest.mark.parametrize("case", list(WALK_CASES))
+def test_pallas_page_walk_matches_oracle(case):
+    """Property sweep of the Pallas page walk against the dense oracle:
+    random disjoint tables and random positions, with the corners forced
+    in — ``pos`` 0 on a live table, 0 on an idle slot whose table names
+    only the null block, a page boundary, and a full table."""
+    kv, g, bs, mb, layers, layer, window = WALK_CASES[case]
+    seed = sorted(WALK_CASES).index(case)
+    s = 6
+    rng = np.random.RandomState(100 + seed)
+    q, k_new, v_new, pool_k, pool_v, tables, _, _ = _rand_case(
+        100 + seed, s=s, kv=kv, g=g, hd=16, bs=bs, mb=mb)
+    null = pool_k.shape[0] // bs - 1
+    tables = tables.at[1].set(null)                  # idle slot
+    pos = np.array([0, 0, 2 * bs, mb * bs - 1]
+                   + list(rng.randint(1, mb * bs, s - 4)), np.int32)
+    pos = jnp.asarray(pos)
+    want = np.asarray(jax.jit(_oracle, static_argnums=(7, 8))(
+        q, k_new, v_new, pool_k, pool_v, tables, pos, bs, window))
+    # the layer read sits among others that differ from it
+    stack_k = jnp.stack([pool_k + (i - layer) for i in range(layers)])
+    stack_v = jnp.stack([pool_v - 2 * (i - layer) for i in range(layers)])
+    got = pallas_pda(q[:, 0], k_new, v_new, stack_k, stack_v,
+                     jnp.int32(layer), tables, pos, block_size=bs,
+                     window=window, interpret=True)
+    np.testing.assert_allclose(want, np.asarray(got[:, None]),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_page_span_counts_live_and_windowed_pages():
+    """``page_span`` is what the kernel walks and what the engine counts:
+    [0, ceil(pos / bs)) without a window; with one, from the first page
+    holding a row the query still sees."""
+    pos = np.array([0, 1, 16, 17, 100])
+    first, end = page_span(pos, 16)
+    np.testing.assert_array_equal(first, [0, 0, 0, 0, 0])
+    np.testing.assert_array_equal(end, [0, 1, 1, 2, 7])
+    first, end = page_span(pos, 16, window=20)
+    # rows > pos - 20 are seen: pos 100 sees rows 81.. -> pages 5, 6
+    np.testing.assert_array_equal(first, [0, 0, 0, 0, 5])
+    np.testing.assert_array_equal(end - first, [0, 1, 1, 2, 2])
+    assert pages_per_block(16, 64) == 8 and pages_per_block(4, 5) == 5
+    assert pages_per_block(256, 8) == 1
 
 
 # ---------------------------------------------------------------------------

@@ -365,6 +365,28 @@ def test_online_budgets_and_latency(dense_setup):
     assert cont.sched.idle
 
 
+@pytest.mark.parametrize("window", [0, 5])
+def test_decode_kv_pages_counts_the_kernels_walk(dense_setup, window):
+    """``serve.decode.kv_pages`` adds, each decode step, the table entries
+    the paged decode kernel walks for one layer: ceil(pos / bs) per
+    decoding slot, less the pages a sliding window has left behind."""
+    cfg, _, params = dense_setup
+    cfg = cfg.replace(sliding_window=window)
+    bs, pl = 4, 6
+    _, cont = _engines(cfg, 9, max_slots=2, block_size=bs, max_seq_len=16)
+    for i, mn in enumerate([9, 3, 6]):
+        cont.submit(_prompts(1, pl, seed=i)[0], max_new=mn)
+    outs = cont.drain(params)
+    want = 0
+    for o in outs:             # decode step j of a request runs at pos pl + j
+        for pos in range(pl, pl + len(o.gen) - 1):
+            first = max(pos - window + 1, 0) // bs if window else 0
+            want += -(-pos // bs) - first
+    assert want > 0
+    assert cont.stats()["decode_kv_pages"] == want
+    assert cont.metrics.value("serve.decode.kv_pages") == want
+
+
 def test_on_finish_streams_each_sample(dense_setup):
     """generate() must deliver every finished row the moment it completes,
     in dock-ready (cap-width) format matching the final RolloutResult."""
