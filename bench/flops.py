@@ -15,6 +15,8 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import harness
+
 
 @dataclass(frozen=True)
 class Dims:
@@ -23,28 +25,35 @@ class Dims:
     heads: int
     kv_heads: int
     head_dim: int
-    d_ff: int
     vocab: int
+    layer_matmul_params: int       # matrix weights a token meets in a layer
     weight_bytes: int = 2          # bf16
 
     @classmethod
     def from_config(cls, c: dict) -> "Dims":
-        """From a Hugging Face style ``config.json`` dictionary."""
-        heads = c["num_attention_heads"]
+        """From a Hugging Face style ``config.json`` dictionary; the layer's
+        matrices are counted by the configuration's model family."""
         return cls(layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-                   heads=heads, kv_heads=c["num_key_value_heads"],
-                   head_dim=c.get("head_dim", c["hidden_size"] // heads),
-                   d_ff=c["intermediate_size"], vocab=c["vocab_size"])
-
-    @property
-    def layer_matmul_params(self) -> int:
-        d, h, kv, hd, f = (self.d_model, self.heads, self.kv_heads,
-                           self.head_dim, self.d_ff)
-        return d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * f
+                   heads=c["num_attention_heads"],
+                   kv_heads=c["num_key_value_heads"], head_dim=head_dim(c),
+                   vocab=c["vocab_size"],
+                   layer_matmul_params=harness.family(c).layer_matmul_params(c))
 
     @property
     def head_params(self) -> int:
         return self.d_model * self.vocab
+
+
+def head_dim(c: dict) -> int:
+    return c.get("head_dim", c["hidden_size"] // c["num_attention_heads"])
+
+
+def attention_params(c: dict) -> int:
+    """Weights of attention's q, k, v and output projections."""
+    d, h, kv = (c["hidden_size"], c["num_attention_heads"],
+                c["num_key_value_heads"])
+    hd = head_dim(c)
+    return d * h * hd + 2 * d * kv * hd + h * hd * d
 
 
 def layer_flops(dims: Dims, tokens: int, attn_pairs: int) -> float:

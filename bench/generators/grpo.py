@@ -85,10 +85,11 @@ def make_dataset(t: dict, seed: int):
     return Dataset(make_task(t), max_prompt_len=t["prompt_pad"], seed=seed)
 
 
-def make_trainer(c: dict, t: dict, seed: int, chips: int):
+def make_trainer(c: dict, t: dict, seed: int, chips: int, trace: bool):
     from repro.configs.base import RLConfig
     from repro.core.trainer import GRPOTrainer
     from repro.launch.mesh import make_mesh
+    from repro.obs import Tracer
 
     cfg = program.model_config(c)
     rl = RLConfig(num_generations=t["generations"],
@@ -106,7 +107,9 @@ def make_trainer(c: dict, t: dict, seed: int, chips: int):
             super()._init_state(lambda k: weights.init(c, k),
                                 weights.key(seed))
 
-    return Trainer(cfg, rl, make_dataset(t, seed), seed=seed, mesh=mesh), rl
+    tracer = Tracer(enabled=True) if trace else None
+    return Trainer(cfg, rl, make_dataset(t, seed), seed=seed, mesh=mesh,
+                   tracer=tracer), rl
 
 
 def instrument(trainer, run, rec: dict) -> None:
@@ -179,7 +182,7 @@ def drive(run, devices) -> dict:
     c, t = run.config, run.traffic
     dims = flops.Dims.from_config(c)
     G, N = t["prompts_per_iteration"], t["generations"]
-    trainer, rl = make_trainer(c, t, run.seed, run.chips)
+    trainer, rl = make_trainer(c, t, run.seed, run.chips, run.trace)
     ds = trainer.dataset
     rec = {"keep": True, "rolls": [], "batches": [], "lengths": []}
     instrument(trainer, run, rec)

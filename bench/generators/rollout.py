@@ -17,7 +17,11 @@ step over all slots), then steps the stream until ``fill_groups`` groups
 have finished, which brings it to its steady state: every slot busy, the
 queue near its stationary depth, groups finishing at a steady rate.  The
 window steps the engine until ``--seconds`` have passed, and records each
-step's live slots, queue depth and groups submitted.  After it, a
+step's live slots, queue depth and groups submitted, and the window's
+increase of every counter in the engine's registry, under the registry's
+names (``serve.steps``, ``serve.launches``, ...).  A traced run gives the
+engine an enabled tracer, whose spans (``serve.*``) land in the profiler's
+trace beside the benchmark's.  After it, a
 sample of the finished requests drawn from the seed, the longest among
 them, is scored by the plain reference: the check is the widest gap
 between the engine's log-probability of each served token and the
@@ -33,10 +37,10 @@ import numpy as np
 
 import check
 import flops
+import harness
 import hostload
 import program
 import weights
-from reference import llama
 
 BLOCK_GROUPS = 16
 
@@ -103,6 +107,7 @@ def warm(eng, params, t: dict, vocab: int, seed: int) -> None:
 
 
 def drive(run, devices) -> dict:
+    from repro.obs import Tracer
     from repro.serve.engine import ServingEngine
 
     c, t = run.config, run.traffic
@@ -115,7 +120,8 @@ def drive(run, devices) -> dict:
                         eos_id=c["eos_token_id"], pad_id=c["eos_token_id"],
                         temperature=t["temperature"], max_slots=t["slots"],
                         block_size=t["block_size"],
-                        max_seq_len=hi + t["max_new"]["max"], seed=run.seed)
+                        max_seq_len=hi + t["max_new"]["max"], seed=run.seed,
+                        tracer=Tracer(enabled=True) if run.trace else None)
     warm(eng, params, t, vocab, run.seed)
 
     stream = Stream(t, run.seed, vocab)
@@ -158,7 +164,7 @@ def drive(run, devices) -> dict:
         step(keep=False)
         fill_steps += 1
 
-    s0 = eng.stats()
+    s0, r0 = eng.stats(), eng.metrics.snapshot()["counters"]
     c0 = dict(count)
     log = []                    # per step: end time, live, queue, groups in
     steps_ctx = []
@@ -179,17 +185,20 @@ def drive(run, devices) -> dict:
                 steps_ctx.append(ctx)
             if now - run.window_t0 >= run.seconds:
                 break
-    window_s = time.perf_counter() - run.window_t0
+    window_s = run.window_t1 - run.window_t0
     compiles = run.compiles_in_window()
     host_load = host.since(h0)
     host.close()
-    s1 = eng.stats()
+    s1, r1 = eng.stats(), eng.metrics.snapshot()["counters"]
     delta = {k: s1[k] - s0[k] for k in ("steps", "prefill_tokens",
                                         "shared_prefill_tokens",
                                         "decode_tokens", "sampled_tokens",
                                         "submitted", "finished")}
+    # every counter of the engine's registry, under its own name
+    delta.update({k: v - r0.get(k, 0) for k, v in r1.items()})
     window = {
         "kind": "rollout", "window_s": window_s, "counters": delta,
+        "block_table": list(eng.sched.tables.shape),
         "attempted": count["requests"], "failed": 0,
         "window_compiles": compiles, "host": host_load,
         "stream": stream_summary(log, fill_steps, c0, count),
@@ -286,7 +295,8 @@ def _scorer(c, w, width, quant):
     """Log-probabilities of a request's served tokens under the reference
     (``quant=None``) or the control, every request padded to one width so
     one program serves all."""
-    fn = jax.jit(lambda w, x: llama.token_logp(w, c, x, quant))
+    ref = harness.reference(c)
+    fn = jax.jit(lambda w, x: ref.token_logp(w, c, x, quant))
 
     def score(o):
         toks = np.full((1, width), c["eos_token_id"], np.int32)

@@ -4,7 +4,16 @@ and metric readers by name, runs the cell's generator, prints the result.
 Everything that belongs to one configuration, traffic mix or metric lives
 in a file of its own, found from the names in ``BENCHMARK.json``:
 
-    configs/<config>.json     sizes as run (Hugging Face config.json keys)
+    configs/<config>.json     sizes as run (Hugging Face config.json keys);
+                              ``family`` and ``reference`` name the two
+                              files below
+    families/<family>.py      the model family: ``model_config(c)`` (the
+                              program's ``ModelConfig``), ``shapes(c)`` (the
+                              weights tree) and ``layer_matmul_params(c)``
+                              (matrix weights a token meets in one layer)
+    reference/<reference>.py  the plain reference: ``token_logp(w, c,
+                              tokens, quant)``, in float32 or the control's
+                              precision
     traffic/<traffic>.json    parameters of the mix; ``generator`` names the
                               general generator in generators/<generator>.py
     limits/<cell>.json        the limit of each number the check compares
@@ -22,6 +31,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import functools
 import importlib.util
 import json
 import os
@@ -80,6 +90,24 @@ def reader(metric: str):
 def generator(name: str):
     return load_module(os.path.join(BENCH, "generators", f"{name}.py"),
                        f"bench_generator_{name}")
+
+
+@functools.cache
+def _named(kind: str, name: str):
+    path = os.path.join(BENCH, kind, f"{name}.py")
+    if not os.path.exists(path):
+        raise SystemExit(f"no {kind}/{name}.py")
+    return load_module(path, f"bench_{kind}_{name.replace('.', '_')}")
+
+
+def family(c: dict):
+    """The model family the configuration ``c`` names (``families/``)."""
+    return _named("families", c["family"])
+
+
+def reference(c: dict):
+    """The plain reference the configuration ``c`` names (``reference/``)."""
+    return _named("reference", c["reference"])
 
 
 class CompileCounter:
@@ -155,7 +183,8 @@ class Run:
         self.faults = overrides.get("faults", ())
         self.control = bool(overrides.get("control", False))
         self.readings = self.control_readings = None
-        self.window_t0 = None
+        self.window_t0 = self.window_t1 = None
+        self.window = None
         self.compiles = None
         self.compiles_at_window = {}
         self._trace_dir = None
@@ -186,17 +215,27 @@ class Run:
 
     @contextlib.contextmanager
     def traced(self):
-        """Profile the enclosed part of the window (``--trace 1``)."""
+        """The measured part of the window, profiled with ``--trace 1``.
+        Its clock ends at the close of the enclosed part (``window_t1``);
+        traced, it starts again once the profiler runs and ends before the
+        profiler collects its trace, so that neither is counted as window.
+        The Python tracer stays off: the program's spans and the
+        benchmark's are annotations, which the host tracer records."""
         if not self.trace:
             yield
+            self.window_t1 = time.perf_counter()
             return
         import jax
 
         self._trace_dir = tempfile.mkdtemp(prefix="bench_trace_")
-        jax.profiler.start_trace(self._trace_dir)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(self._trace_dir, profiler_options=opts)
         try:
+            self.window_t0 = time.perf_counter()
             with jax.profiler.TraceAnnotation("bench.window"):
                 yield
+            self.window_t1 = time.perf_counter()
         finally:
             jax.profiler.stop_trace()
 
@@ -230,7 +269,7 @@ def main(argv, *, t_start: float | None = None, require_chip: bool = True,
     tests: they skip the look for a TPU, shrink the configuration and name
     the cells (``BENCHMARK.json`` where not given).  ``keep`` (a list)
     receives the run's ``Run``, whose ``readings`` and ``control_readings``
-    the calibration reads."""
+    the calibration reads, and its ``window``."""
     t_start = time.perf_counter() if t_start is None else t_start
     args = parse(argv)
     bench = benchmark() if bench is None else bench
@@ -254,7 +293,7 @@ def main(argv, *, t_start: float | None = None, require_chip: bool = True,
     devices = jax.devices()[:run.chips]
     run.compiles = CompileCounter()
     gen = generator(run.traffic["generator"])
-    window = gen.drive(run, devices)
+    window = run.window = gen.drive(run, devices)
     device["memory_peak_bytes"] = window.pop("memory_peak_bytes")
     if run.trace and window.get("trace"):
         device["busy_s"] = window["trace"]["busy_s"]
@@ -297,6 +336,8 @@ def main(argv, *, t_start: float | None = None, require_chip: bool = True,
         for n, v in top:
             if "custom-call" in n[:200]:
                 print(f"bench custom call: {v!r} {n[:600]}", file=sys.stderr)
+        for n, row in sorted(window["trace"]["spans"].items()):
+            print(f"bench span: {n} {json.dumps(row)}", file=sys.stderr)
     sys.stderr.flush()
     print(f"bench: correct={correct}", file=sys.stderr)
     for c in checks:

@@ -5,6 +5,8 @@ from __future__ import annotations
 import os
 import sys
 
+import harness
+
 SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))), "src")
 if SRC not in sys.path:
@@ -12,28 +14,9 @@ if SRC not in sys.path:
 
 
 def model_config(c: dict):
-    """The program's ``ModelConfig`` for a Hugging Face style config."""
-    from repro.configs.base import ModelConfig
-
-    heads = c["num_attention_heads"]
-    if c.get("use_sliding_window"):
-        raise ValueError("sliding-window layers are not modelled here")
-    return ModelConfig(
-        name=f"{c['model_type']}-{c['num_hidden_layers']}l",
-        arch_type="dense",
-        num_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"],
-        vocab_size=c["vocab_size"],
-        num_heads=heads,
-        num_kv_heads=c["num_key_value_heads"],
-        head_dim=c.get("head_dim", c["hidden_size"] // heads),
-        qkv_bias=bool(c.get("attention_bias", False)),
-        rope_theta=float(c["rope_theta"]),
-        d_ff=c["intermediate_size"],
-        dtype=c["torch_dtype"],
-        norm_eps=float(c["rms_norm_eps"]),
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-    )
+    """The program's ``ModelConfig`` for a Hugging Face style config, as
+    the configuration's model family builds it."""
+    return harness.family(c).model_config(c)
 
 
 def memory_peak_bytes(devices) -> int | None:

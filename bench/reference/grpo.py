@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from reference import llama
+import harness
 
 
 def advantages(rewards: np.ndarray, n: int) -> np.ndarray:
@@ -79,8 +79,10 @@ class GRPOReference:
         """``rl``: kl_coef, clip_eps, lr, betas, eps, weight_decay,
         grad_clip.  ``shard(tree)`` places arrays (several chips)."""
         self.c, self.rl, self.quant = c, rl, quant
+        self.model = harness.reference(c)
         self.shard = shard or (lambda t: t)
-        self._logp = jax.jit(lambda w, t: llama.token_logp(w, c, t, quant))
+        self._logp = jax.jit(lambda w, t: self.model.token_logp(w, c, t,
+                                                                quant))
         self._grad = jax.jit(jax.value_and_grad(self._loss))
         self._adam = {}
 
@@ -90,7 +92,7 @@ class GRPOReference:
 
     def _loss(self, w, tokens, mask, adv, old, ref):
         rl = self.rl
-        logp = llama.token_logp(w, self.c, tokens, self.quant)
+        logp = self.model.token_logp(w, self.c, tokens, self.quant)
         m = mask[:, 1:]
         ratio = jnp.exp(logp - old)
         a = adv[:, None]

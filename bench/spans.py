@@ -1,11 +1,11 @@
 """Program spans in a profiler trace, beside the benchmark's own.
 
-``xplane.reduce`` names each idle gap of the device by the innermost
-``bench.*`` span the host was in.  The program mirrors its own ``repro.obs``
-spans into the trace as well, each on the host thread that ran it: the
-serving engine's phases (``serve.*``), the graph executor's ``stage.*`` and
-``reshard.*``, the trainer's ``iteration``.  This module reads both kinds
-and reduces them against the device's busy intervals:
+The benchmark's spans are named ``bench.*``.  The program mirrors its own
+``repro.obs`` spans into the trace as well, each on the host thread that
+ran it: the serving engine's phases (``serve.*``), the graph executor's
+``stage.*`` and ``reshard.*``, the trainer's ``iteration``.  This module
+reads both kinds and reduces them against the device's busy intervals;
+``xplane.reduce`` names the idle gaps and tabulates the spans with it:
 
 * ``host_spans``: the spans of either kind in a trace, per host thread;
 * ``innermost``: the innermost span at each of an ascending run of
@@ -25,15 +25,15 @@ import bisect
 import heapq
 from collections import defaultdict
 
-import xplane
-
+WINDOW_SPAN = "bench.window"
+BENCH_PREFIX = "bench."
 PROGRAM_PREFIXES = ("serve.", "stage.", "reshard.")
 PROGRAM_NAMES = ("iteration",)
 
 
 def is_span(name: str) -> bool:
     """A benchmark span or one of the program's spans."""
-    return (name.startswith(xplane.SPAN_PREFIX)
+    return (name.startswith(BENCH_PREFIX)
             or name.startswith(PROGRAM_PREFIXES) or name in PROGRAM_NAMES)
 
 
@@ -43,7 +43,11 @@ def host_spans(path: str) -> dict:
     from jax.profiler import ProfileData
 
     with open(path, "rb") as f:
-        pd = ProfileData.from_serialized_xspace(f.read())
+        return threads_in(ProfileData.from_serialized_xspace(f.read()))
+
+
+def threads_in(pd) -> dict:
+    """``host_spans`` of a trace already read (``ProfileData``)."""
     threads = defaultdict(list)
     for plane in pd.planes:
         if not plane.name.startswith("/host:"):
@@ -60,7 +64,7 @@ def innermost(spans, points) -> list[str]:
     """The innermost span's name at each of ``points`` (ascending), or
     ``"none"`` where no span but ``bench.window`` holds it.  Spans are
     ``(name, start, end)`` in any order, from any threads."""
-    order = sorted((sp for sp in spans if sp[0] != xplane.WINDOW_SPAN),
+    order = sorted((sp for sp in spans if sp[0] != WINDOW_SPAN),
                    key=lambda sp: sp[1])
     heap, out, i = [], [], 0
     for t in points:

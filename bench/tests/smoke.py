@@ -40,17 +40,20 @@ def overrides(generator: str, mesh=None, faults=(), control=False,
 
 
 def run_cell(cell: str, generator: str, *, seed=1234, seconds=None, trace=0,
-             limits=None, env=None, **kw):
+             limits=None, env=None, bench_dir=None, bench=None, **kw):
     """Runs the harness on the CPU in a child process (so that nothing it
     sets in JAX reaches the other tests of the worker), with the look for a
     chip skipped; returns (exit code, last stdout line parsed, the run's
-    ``limits``, ``readings`` and ``control_readings`` as attributes)."""
+    ``limits``, ``readings`` and ``control_readings`` and its window's
+    ``counters`` and trace ``spans`` as attributes).  ``bench_dir`` runs
+    another copy of the benchmark, ``bench`` names its cells and metrics
+    (``BENCHMARK.json`` with the kept-out cells where not given)."""
     ov = overrides(generator, **kw)
     seconds = SMOKE_SECONDS[generator] if seconds is None else seconds
     if limits is not None:
         ov["limits"] = limits
-    code = CHILD.format(paths=[BENCH, SRC], ov=json.dumps(ov), kept=KEPT_OUT,
-                        argv=[
+    code = CHILD.format(paths=[bench_dir or BENCH, SRC], ov=json.dumps(ov),
+                        kept=KEPT_OUT, bench=json.dumps(bench), argv=[
         "--workload", cell, "--seed", str(seed), "--seconds", str(seconds),
         "--trace", str(trace)], cpu_peaks=bool(trace))
     env = dict(os.environ, JAX_PLATFORMS="cpu", **(env or {}))
@@ -75,9 +78,11 @@ CHILD = r"""
 import contextlib, io, json, sys
 sys.path[:0] = {paths!r}
 import harness, peaks
-bench, extra = harness.benchmark(), json.load(open({kept!r}))
-for key in ("workloads", "end_to_end", "per_layer"):
-    bench[key] += extra[key]
+bench = json.loads({bench!r})
+if bench is None:
+    bench, extra = harness.benchmark(), json.load(open({kept!r}))
+    for key in ("workloads", "end_to_end", "per_layer"):
+        bench[key] += extra[key]
 if {cpu_peaks!r}:
     # a stand-in peak for the CPU backend, so the readers that divide by
     # a peak have one; the numbers are never reported as a device's
@@ -90,8 +95,11 @@ with contextlib.redirect_stdout(buf):
                       overrides=json.loads({ov!r}), keep=kept, bench=bench)
 lines = buf.getvalue().strip().splitlines()
 r = kept[0]
+w = r.window or {{}}
 print("RESULT " + json.dumps({{
     "rc": rc, "out": json.loads(lines[-1]) if lines else None,
     "run": {{"limits": r.limits, "readings": r.readings,
-             "control_readings": r.control_readings}}}}))
+             "control_readings": r.control_readings,
+             "counters": w.get("counters"),
+             "spans": (w.get("trace") or {{}}).get("spans")}}}}))
 """

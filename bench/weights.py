@@ -14,31 +14,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from flops import Dims
+import harness
 
 
 def shapes(c: dict) -> dict:
-    """Leaf name -> shape for the configuration ``c``."""
-    d = Dims.from_config(c)
-    n, dm, h, kv, hd, f, v = (d.layers, d.d_model, d.heads, d.kv_heads,
-                              d.head_dim, d.d_ff, d.vocab)
-    attn = {"wq": (n, dm, h * hd), "wk": (n, dm, kv * hd),
-            "wv": (n, dm, kv * hd), "wo": (n, h * hd, dm)}
-    if has_qkv_bias(c):
-        attn.update(bq=(n, h * hd), bk=(n, kv * hd), bv=(n, kv * hd))
-    return {
-        "embed": (v, dm),
-        "lm_head": (dm, v),
-        "layers": {"ln1": {"scale": (n, dm)}, "attn": attn,
-                   "ln2": {"scale": (n, dm)},
-                   "mlp": {"w_gate": (n, dm, f), "w_up": (n, dm, f),
-                           "w_down": (n, f, dm)}},
-        "ln_f": {"scale": (dm,)},
-    }
-
-
-def has_qkv_bias(c: dict) -> bool:
-    return bool(c.get("attention_bias", False))
+    """Leaf name -> shape for the configuration ``c``, as its model family
+    lays the tree out."""
+    return harness.family(c).shapes(c)
 
 
 def key(seed: int):

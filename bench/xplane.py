@@ -1,15 +1,16 @@
 """Reduce a JAX profiler trace (``.xplane.pb``) to the device's busy time,
-the device operations that took most time, and the idle gaps by what the
-benchmark's host code was doing.
+the device operations that took most time, the idle gaps by what the host
+was doing, and the host's spans (``spans.span_table``).
 
 Layout read (``jax.profiler.ProfileData``): a TPU is a plane named
 ``/device:TPU:<n>`` whose line ``XLA Ops`` holds one event per operation
-run; the host plane ``/host:CPU`` holds the benchmark's
-``jax.profiler.TraceAnnotation`` spans (named ``bench.<call>``), among them
-``bench.window`` around the traced part of the window.  On the CPU backend
-(the rehearsal tests) no device plane exists, and the host events that
-carry an ``hlo_op`` stat stand for the device's operations.  All times are
-on the profiler's one clock, in nanoseconds.
+run; the host plane ``/host:CPU`` holds, one line a thread, the
+benchmark's ``jax.profiler.TraceAnnotation`` spans (named
+``bench.<call>``), among them ``bench.window`` around the traced part of
+the window, and the program's own spans (``spans.is_span``).  On the CPU
+backend (the rehearsal tests) no device plane exists, and the host events
+that carry an ``hlo_op`` stat stand for the device's operations.  All times
+are on the profiler's one clock, in nanoseconds.
 """
 from __future__ import annotations
 
@@ -17,8 +18,9 @@ import glob
 import os
 from collections import defaultdict
 
-WINDOW_SPAN = "bench.window"
-SPAN_PREFIX = "bench."
+import spans as program_spans
+
+WINDOW_SPAN = program_spans.WINDOW_SPAN
 TOP = 10
 
 
@@ -42,13 +44,13 @@ def short_name(op: str) -> str:
 
 
 def load(path: str) -> dict:
-    """Device op intervals per device, host spans and the window bounds."""
+    """Device op intervals per device, host spans (all, and per host
+    thread) and the window bounds."""
     from jax.profiler import ProfileData
 
     with open(path, "rb") as f:
         pd = ProfileData.from_serialized_xspace(f.read())
     devices: dict[str, list] = {}
-    spans: list = []
     cpu_ops: list = []
     for plane in pd.planes:
         if plane.name.startswith("/device:TPU:"):
@@ -60,12 +62,13 @@ def load(path: str) -> dict:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for n, s, e, ev in _events(line):
-                    if n.startswith(SPAN_PREFIX):
-                        spans.append((n, s, e))
-                    elif e > s and any(k == "hlo_op" for k, _ in ev.stats):
+                    if (e > s and not program_spans.is_span(n)
+                            and any(k == "hlo_op" for k, _ in ev.stats)):
                         cpu_ops.append((n, s, e))
     if not devices and cpu_ops:
         devices["/host:CPU"] = cpu_ops
+    threads = program_spans.threads_in(pd)
+    spans = [sp for thread in threads.values() for sp in thread]
     win = [(s, e) for n, s, e in spans if n == WINDOW_SPAN]
     if win:
         w0, w1 = min(s for s, _ in win), max(e for _, e in win)
@@ -73,7 +76,8 @@ def load(path: str) -> dict:
         allops = [iv for ops in devices.values() for iv in ops]
         w0 = min(s for _, s, _ in allops)
         w1 = max(e for _, _, e in allops)
-    return {"devices": devices, "spans": spans, "window": (w0, w1)}
+    return {"devices": devices, "spans": spans, "threads": threads,
+            "window": (w0, w1)}
 
 
 def union(intervals, lo: float, hi: float) -> list:
@@ -101,6 +105,8 @@ def gaps(busy, lo: float, hi: float) -> list:
 
 
 def _innermost(spans, t: float) -> str:
+    """The innermost span at ``t`` by a scan over every span: the plain
+    answer that ``spans.innermost``'s sweep is tested against."""
     best = None
     for n, s, e in spans:
         if n != WINDOW_SPAN and s <= t <= e and (
@@ -112,8 +118,10 @@ def _innermost(spans, t: float) -> str:
 def reduce(path: str, chips: int = 1) -> dict:
     """busy_s and window_s (seconds, busy averaged over the devices), the
     device time of every op and the top ten (summed over devices, divided
-    by their number), and the idle gaps of the first device summed by the
-    innermost benchmark span the host was in at the gap's middle."""
+    by their number), the idle gaps of the first device summed by the
+    innermost span, the benchmark's or the program's, that the host was in
+    at the gap's middle, and ``spans``: each span's count, time, self time
+    and the first device's idle time inside it (``spans.span_table``)."""
     t = load(path)
     lo, hi = t["window"]
     devs = sorted(t["devices"])[:max(chips, 1)]
@@ -130,10 +138,7 @@ def reduce(path: str, chips: int = 1) -> dict:
                 by_op[n] += e - s
     nd = len(devs)
     first = union(((s, e) for _, s, e in t["devices"][devs[0]]), lo, hi)
-    idle = defaultdict(float)
-    spans = sorted(t["spans"], key=lambda x: x[1])
-    for s, e in gaps(first, lo, hi):
-        idle[_innermost(spans, (s + e) / 2)] += e - s
+    idle = program_spans.idle_by_span(t["spans"], gaps(first, lo, hi))
     top_ops = sorted(by_op.items(), key=lambda kv: -kv[1])[:TOP]
     top_idle = sorted(idle.items(), key=lambda kv: -kv[1])[:TOP]
     return {
@@ -142,5 +147,6 @@ def reduce(path: str, chips: int = 1) -> dict:
         "device_ops": [[short_name(n), v / nd / 1e9] for n, v in top_ops],
         "idle_gaps": [[n, v / 1e9] for n, v in top_idle],
         "op_s": {n: v / nd / 1e9 for n, v in by_op.items()},
+        "spans": program_spans.span_table(t["threads"], first, lo, hi),
         "devices": nd,
     }
