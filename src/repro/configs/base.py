@@ -26,6 +26,8 @@ class ModelConfig:
     num_kv_heads: int = 0
     head_dim: int = 0
     qkv_bias: bool = False
+    qk_norm: bool = False            # RMSNorm over head_dim on q and k, before
+    #                                  RoPE, one scale a layer each (qwen3)
     rope_theta: float = 10_000.0
     sliding_window: int = 0          # 0 => full causal attention
     mrope_sections: Tuple[int, ...] = ()   # M-RoPE (qwen2-vl): head_dim split t/h/w
@@ -36,7 +38,13 @@ class ModelConfig:
     experts_per_token: int = 0
     moe_capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
-    moe_impl: str = "dispatch"       # dispatch (capacity einsum) | gmm (dropless)
+    moe_impl: str = "dispatch"       # training's expert path: dispatch
+    #                                  (capacity einsum) | gmm (dropless);
+    #                                  serving is always dropless (gmm)
+    moe_held: Tuple[int, ...] = ()   # (first, count): the experts this
+    #                                  layer holds of the router's
+    #                                  num_experts (expert parallelism's
+    #                                  share); () holds them all
     # --- SSM (mamba2 / SSD) ---
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -66,6 +74,11 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def experts_held(self) -> Tuple[int, int]:
+        """(first expert id, count) of the experts this layer holds."""
+        return tuple(self.moe_held) or (0, self.num_experts)
 
     @property
     def d_inner(self) -> int:        # SSM inner width

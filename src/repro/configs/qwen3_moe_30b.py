@@ -1,8 +1,9 @@
 """qwen3-moe-30b (Qwen3-30B-A3B) — the paper's own MoE evaluation model
 [arXiv:2505.09388].
 
-48L d_model=2048 32H (kv=4, head_dim=128) 128 experts top-8, expert d_ff=768,
-vocab=151936.
+48L d_model=2048 32H (kv=4, head_dim=128) with per-head q/k RMSNorm, 128
+experts top-8 (softmax, renormalised over the 8), expert d_ff=768, no
+shared expert, vocab=151936, rms_norm_eps=1e-6.
 """
 from repro.configs.base import ModelConfig
 
@@ -15,10 +16,12 @@ CONFIG = ModelConfig(
     num_heads=32,
     num_kv_heads=4,
     head_dim=128,
+    qk_norm=True,
     d_ff=768,
     num_experts=128,
     experts_per_token=8,
     rope_theta=1e6,
+    norm_eps=1e-6,
 )
 
 

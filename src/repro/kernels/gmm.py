@@ -6,6 +6,20 @@ row tile is data-dependent, so it is passed through scalar prefetch
 (PrefetchScalarGridSpec) and consumed by the weight BlockSpec index_map —
 exactly the megablocks-on-TPU adaptation: contiguous MXU tiles instead of
 GPU gather-scatter.
+
+T is a static worst case (every routed row plus each group's padding); the
+rows actually routed fill only its first ``sum(group_sizes) / tile_t``
+tiles, a count also passed through scalar prefetch.  Tiles past it map
+every block to the last live tile's, so the pipeline fetches no new x or
+weight block and writes no output back for them, and the kernel skips
+their MXU work: device time follows the rows routed, not T.  Their output
+rows are left unwritten and must not be read.  Operands enter the MXU in
+their own type (bfloat16 stays bfloat16) with float32 accumulation.
+
+The weights may be a layer stack ``(L, E, d, f)`` with the layer's index
+(scalar prefetch too): the kernel then reads that layer's blocks in place,
+where a slice of the stack before the call would copy the layer's whole
+table (XLA cannot fuse a slice into a custom call).
 """
 from __future__ import annotations
 
@@ -22,22 +36,35 @@ from jax.experimental.pallas import tpu as pltpu
 VMEM_BOUNDS = {"d": 5120}
 
 
-def _gmm_kernel(tile_gid_ref, x_ref, w_ref, o_ref):
-    del tile_gid_ref  # consumed by the index_map
-    x = x_ref[...].astype(jnp.float32)          # (tile_t, d)
-    w = w_ref[0].astype(jnp.float32)            # (d, block_f)
-    o_ref[...] = jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    ).astype(o_ref.dtype)
+def _gmm_kernel(tile_gid_ref, live_ref, layer_ref, x_ref, w_ref, o_ref):
+    del tile_gid_ref, layer_ref  # consumed by the index_maps
+
+    @pl.when(pl.program_id(1) < live_ref[0])
+    def _():
+        x, w = x_ref[...], w_ref[0, 0]
+        # bfloat16 products are exact in one pass; float32 takes all passes
+        exact = (jax.lax.Precision.HIGHEST if x.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+        o_ref[...] = jax.lax.dot_general(
+            x, w, (((1,), (0,)), ((), ())), precision=exact,
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
+def _last_live(i, live):
+    return jnp.minimum(i, jnp.maximum(live[0] - 1, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("tile_t", "block_f", "interpret"))
-def gmm(x: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray, *,
-        tile_t: int = 128, block_f: int = 512,
+def gmm(x: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
+        layer=0, *, tile_t: int = 128, block_f: int = 512,
         interpret: bool = False) -> jnp.ndarray:
-    """x: (T, d) group-sorted, tile-aligned; w: (E, d, f); group_sizes: (E,)."""
+    """x: (T, d) group-sorted, tile-aligned; w: (E, d, f), or (L, E, d, f)
+    read at ``layer``; group_sizes: (E,) multiples of ``tile_t`` summing to
+    at most T."""
+    if w.ndim == 3:
+        w = w[None]
     t, d = x.shape
-    e, _, f = w.shape
+    _, e, _, f = w.shape
     assert t % tile_t == 0, (t, tile_t)
     block_f = min(block_f, f)
     while f % block_f:
@@ -50,43 +77,29 @@ def gmm(x: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray, *,
     tile_start = jnp.arange(nt, dtype=jnp.int32) * tile_t
     tile_gid = jnp.clip(
         jnp.searchsorted(offsets, tile_start, side="right"), 0, e - 1
-    ).astype(jnp.int32)  # trailing padding tiles compute with the last
-    # expert's weights; their rows are never read back
+    ).astype(jnp.int32)
+    live = (offsets[-1:] // tile_t).astype(jnp.int32)     # tiles in use
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
+    # the row tiles vary fastest, so a dead tile repeats the last live
+    # tile's blocks and the pipeline skips its copies
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nt, f // block_f),
+        num_scalar_prefetch=3,
+        grid=(f // block_f, nt),
         in_specs=[
-            pl.BlockSpec((tile_t, d), lambda i, j, gid: (i, 0)),
-            pl.BlockSpec((1, d, block_f), lambda i, j, gid: (gid[i], 0, j)),
+            pl.BlockSpec((tile_t, d), lambda j, i, gid, live, layer: (
+                _last_live(i, live), 0)),
+            pl.BlockSpec((1, 1, d, block_f), lambda j, i, gid, live, layer: (
+                layer[0], gid[_last_live(i, live)], 0, j)),
         ],
-        out_specs=pl.BlockSpec((tile_t, block_f), lambda i, j, gid: (i, j)),
+        out_specs=pl.BlockSpec(
+            (tile_t, block_f),
+            lambda j, i, gid, live, layer: (_last_live(i, live), j)),
     )
     return pl.pallas_call(
         _gmm_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, f), x.dtype),
         interpret=interpret,
-    )(tile_gid, x, w)
-
-
-def pad_groups(x: jnp.ndarray, gid: jnp.ndarray, num_groups: int,
-               tile_t: int = 128):
-    """Helper: sort rows of ``x`` by group id and pad every group to a
-    ``tile_t`` multiple.  Returns (x_sorted_padded, padded_group_sizes,
-    inverse_gather_idx, valid_mask) so callers can un-permute the output."""
-    t = x.shape[0]
-    order = jnp.argsort(gid, stable=True)
-    sizes = jnp.bincount(gid, length=num_groups)
-    padded = ((sizes + tile_t - 1) // tile_t) * tile_t
-    pad_total = int(num_groups * tile_t)  # worst-case extra rows (static)
-    out_rows = t + pad_total
-    starts = jnp.concatenate([jnp.zeros((1,), sizes.dtype),
-                              jnp.cumsum(padded)[:-1]])
-    # destination row of each (sorted) source row
-    src_group = jnp.sort(gid, stable=True)
-    within = jnp.arange(t) - jnp.take(jnp.concatenate(
-        [jnp.zeros((1,), sizes.dtype), jnp.cumsum(sizes)[:-1]]), src_group)
-    dest = jnp.take(starts, src_group) + within
-    xs = jnp.zeros((out_rows, x.shape[1]), x.dtype).at[dest].set(x[order])
-    return xs, padded, order, dest
+        name="moe_gmm",
+    )(tile_gid, live, layer, x, w)

@@ -499,23 +499,28 @@ def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, layer, tables,
 # ---------------------------------------------------------------------------
 
 def gmm(x: jnp.ndarray, w: jnp.ndarray, group_sizes: jnp.ndarray,
-        tile_t: int = 128) -> jnp.ndarray:
-    """Grouped matmul: x (T,d) sorted by group, w (E,d,f), group_sizes (E,).
+        layer=0, tile_t: int = 128, block_f: int = 512) -> jnp.ndarray:
+    """Grouped matmul: x (T,d) sorted by group, w (E,d,f) or a layer stack
+    (L,E,d,f) read at ``layer``, group_sizes (E,) summing to at most T; rows
+    past the groups are not computed (TPU: left unwritten, CPU: zero).
 
     TPU path: Pallas kernel with MXU-aligned tiles (caller must align group
-    boundaries to ``tile_t``).  CPU path: one-hot einsum (dense over E — used
-    only at smoke scale).
+    boundaries to ``tile_t``), reading a stack's layer in place.  CPU path:
+    each row tile times its group's weights, gathered per tile (tiles past
+    the groups give zero).
     """
     if _use_pallas():
         from repro.kernels import gmm as _k
 
-        return _k.gmm(x, w, group_sizes, tile_t=tile_t,
-                      interpret=_interpret())
-    t = x.shape[0]
+        return _k.gmm(x, w, group_sizes, layer, tile_t=tile_t,
+                      block_f=block_f, interpret=_interpret())
+    if w.ndim == 4:
+        w = w[layer]
+    t, d = x.shape
     e = w.shape[0]
+    nt = t // tile_t
     bounds = jnp.cumsum(group_sizes)
-    gid = jnp.sum(jnp.arange(t)[:, None] >= bounds[None, :], axis=-1)
-    onehot = jax.nn.one_hot(gid, e, dtype=x.dtype)  # (T, E)
-    xe = jnp.einsum("td,te->etd", x, onehot)
-    ye = jnp.einsum("etd,edf->etf", xe, w.astype(x.dtype))
-    return jnp.einsum("etf,te->tf", ye, onehot)
+    gid = jnp.searchsorted(bounds, jnp.arange(nt) * tile_t, side="right")
+    wt = w[jnp.minimum(gid, e - 1)].astype(x.dtype)          # (nt, d, f)
+    y = jnp.einsum("ntd,ndf->ntf", x.reshape(nt, tile_t, d), wt)
+    return jnp.where((gid < e)[:, None, None], y, 0).reshape(t, -1)

@@ -66,6 +66,9 @@ def attn_init(cfg: ModelConfig, key, layers: int | None = None) -> dict:
         p["bq"] = jnp.zeros(lead + (h * hd,), cdtype(cfg))
         p["bk"] = jnp.zeros(lead + (kv * hd,), cdtype(cfg))
         p["bv"] = jnp.zeros(lead + (kv * hd,), cdtype(cfg))
+    if cfg.qk_norm:
+        p["q_norm"] = {"scale": jnp.ones(lead + (hd,), cdtype(cfg))}
+        p["k_norm"] = {"scale": jnp.ones(lead + (hd,), cdtype(cfg))}
     return p
 
 
@@ -79,8 +82,12 @@ def _qkv(p: dict, cfg: ModelConfig, x: jnp.ndarray):
         q = q + p["bq"]
         k = k + p["bk"]
         v = v + p["bv"]
-    return (q.reshape(b, s, h, hd), k.reshape(b, s, kv, hd),
-            v.reshape(b, s, kv, hd))
+    q, k = q.reshape(b, s, h, hd), k.reshape(b, s, kv, hd)
+    if cfg.qk_norm:
+        # per-head RMSNorm over head_dim, before every path's RoPE
+        q = ops.rmsnorm(q, p["q_norm"]["scale"], cfg.norm_eps)
+        k = ops.rmsnorm(k, p["k_norm"]["scale"], cfg.norm_eps)
+    return q, k, v.reshape(b, s, kv, hd)
 
 
 def attn_project_out(p: dict, y: jnp.ndarray) -> jnp.ndarray:

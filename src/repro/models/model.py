@@ -12,6 +12,10 @@
                                          tokens, start, block_size=bs,
                                          last=n)  # serving chunked prefill
 
+MoE's ``decode_paged`` and ``prefill_paged`` return the expert layers'
+counts after these (``moe.STATS``); its ``prefill`` does with
+``moe_stats=True``.
+
 ``batch`` is a dict: tokens (B, S) int32, plus family extras —
 vision_embeds (B, P, d) for vlm, frames (B, enc_seq, d) for audio.
 ``aux`` is the MoE load-balance loss (0.0 elsewhere).

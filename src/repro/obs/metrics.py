@@ -5,6 +5,10 @@ tracer's timeline: the tracer answers WHEN, the registry answers HOW MUCH.
 Always on — every operation is a dict lookup plus an add/append, cheap
 enough for the serving hot loop — and thread-safe under one lock.
 
+Counters a program keeps on a device (``add_source``) are read when the
+registry is snapshotted, not when they change, so keeping them costs the
+hot loop no read.
+
 ``snapshot()`` is the machine-readable contract: a plain, JSON-serializable
 dict with deterministically sorted keys, histograms summarized to
 count/sum/mean/min/max + nearest-rank percentiles.  ``engine.stats()`` and
@@ -39,6 +43,7 @@ class MetricsRegistry:
         self._counters: dict[str, int] = {}  # guarded-by: _lock
         self._gauges: dict[str, float] = {}  # guarded-by: _lock
         self._hists: dict[str, list[float]] = {}  # guarded-by: _lock
+        self._sources: list = []   # [names, read, last totals]
 
     # -- write --------------------------------------------------------------
     def inc(self, name: str, n: int = 1) -> None:
@@ -58,6 +63,22 @@ class MetricsRegistry:
     def observe(self, name: str, value: float) -> None:
         with self._lock:
             self._hists.setdefault(name, []).append(float(value))
+
+    def add_source(self, names: tuple, read) -> None:
+        """Counters ``names`` whose running totals ``read()`` returns, in
+        the same order (a device accumulator, say, wrapping at 2**32); each
+        ``snapshot()`` calls it once and adds what the totals grew by."""
+        self._sources.append([tuple(names), read, [0] * len(names)])
+
+    def _fold_sources(self) -> None:
+        for src in self._sources:
+            names, read, last = src
+            now = [int(v) for v in read()]
+            with self._lock:
+                for name, a, b in zip(names, last, now):
+                    self._counters[name] = (self._counters.get(name, 0)
+                                            + (b - a) % 2**32)
+            src[2] = now
 
     # -- read ---------------------------------------------------------------
     def value(self, name: str, default=0):
@@ -98,6 +119,7 @@ class MetricsRegistry:
         ``{"counters": {...}, "gauges": {...}, "histograms": {name:
         summary}}`` with sorted keys.  Repeated calls with no writes in
         between return equal dicts (pinned by tests)."""
+        self._fold_sources()
         with self._lock:
             counters = dict(sorted(self._counters.items()))
             gauges = dict(sorted(self._gauges.items()))

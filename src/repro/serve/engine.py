@@ -98,6 +98,7 @@ from repro.core.rollout import (RolloutResult, request_stream, sample_tokens,
                                 sampled_drawer)
 from repro.kernels.paged_attention import page_span
 from repro.models.model import build_model
+from repro.models.moe import STATS as MOE_STATS
 from repro.models.transformer import paged_window
 from repro.obs import NULL_SPAN, MetricsRegistry, get_tracer
 from repro.serve.host_tier import HostKVTier, SwapWorkerError
@@ -115,6 +116,16 @@ def prefill_bucket(n: int) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def _counted(fn):
+    """``fn``, whose outputs end with an MoE model's expert counts, taking
+    the counts' accumulator as its last argument and returning it, in the
+    counts' place, with them added (one program, no extra launch)."""
+    def run(*args):
+        *out, counts = fn(*args[:-1])
+        return (*out, args[-1] + counts)
+    return run
 
 
 @dataclass
@@ -206,9 +217,19 @@ class ServingEngine:
         #   serve.host_reads = device values read to the host, both counted
         #   at their call sites; serve.decode.kv_pages = pool pages the
         #   paged decode kernel walks for ONE layer, summed over decode
-        #   steps (from the host positions, no device read)
+        #   steps (from the host positions, no device read);
+        #   serve.moe.{rows,padded_rows,calls,experts} = the expert layers'
+        #   work (models/moe.py STATS), accumulated on the device by the
+        #   programs that run them and read when the registry is
+        #   snapshotted (that read is not in serve.host_reads)
         self.tracer = tracer if tracer is not None else get_tracer()
         self.metrics = MetricsRegistry()
+        self._moe_acc = None
+        if cfg.is_moe:
+            self._moe_acc = jnp.zeros((len(MOE_STATS),), jnp.int32)
+            self.metrics.add_source(
+                tuple(f"serve.moe.{n}" for n in MOE_STATS),
+                lambda: np.asarray(self._moe_acc))
         # the host tier outlives pool regrows (_ensure_state rebuilds the
         # cache; host entries are content-addressed by prefix key, so they
         # stay valid against any device pool shape)
@@ -222,10 +243,11 @@ class ServingEngine:
         self._step_prefill = 0
         if max_seq_len is not None:
             self._ensure_state(max_seq_len)
-        self._prefill = jax.jit(self._prefill_impl)
-        self._chunk = jax.jit(self._chunk_impl)
+        counted = _counted if cfg.is_moe else (lambda fn: fn)
+        self._prefill = jax.jit(counted(self._prefill_impl))
+        self._chunk = jax.jit(counted(self._chunk_impl))
         self._sample = jax.jit(self._sample_impl)
-        self._step = jax.jit(self._step_impl, donate_argnums=(1, 2))
+        self._step = jax.jit(counted(self._step_impl), donate_argnums=(1, 2))
         self._write = jax.jit(scatter_prefill, donate_argnums=(0,))
         # sampled draws go through the PROCESS-SHARED drawer (one compiled
         # function per sampling config, the same object RolloutEngine uses)
@@ -343,13 +365,24 @@ class ServingEngine:
     # ------------------------------------------------------------------
     # jitted pieces
     # ------------------------------------------------------------------
+    def _run(self, fn, *args):
+        """Call one of the jitted programs below; an MoE engine hands it
+        the expert counts' accumulator and keeps the one it returns."""
+        if self._moe_acc is None:
+            return fn(*args)
+        *out, self._moe_acc = fn(*args, self._moe_acc)
+        return tuple(out)
+
     def _prefill_impl(self, params, batch, last=None):
         """``last`` (traced () int32) selects the logits position for
         bucket-padded admission prefills; None (the batch generate() path)
-        keeps the final position, bit-identical to RolloutEngine."""
+        keeps the final position, bit-identical to RolloutEngine.  An MoE
+        model's expert counts come last."""
         b, s = batch["tokens"].shape
         cache = self.model.init_cache(self.cfg, b, s)
-        return self.model.prefill(params, self.cfg, batch, cache, last=last)
+        counts = {"moe_stats": True} if self.cfg.is_moe else {}
+        return self.model.prefill(params, self.cfg, batch, cache, last=last,
+                                  **counts)
 
     def _sample_impl(self, logits):
         """GREEDY first-token sampling (argmax consumes no key; the graph is
@@ -374,7 +407,8 @@ class ServingEngine:
         GREEDY engines sample fused in this graph (argmax — the pre-streams
         graph, so greedy bit-contracts are untouched) and return
         ``(pool_k, pool_v, nxt, lp)``.  SAMPLED engines return
-        ``(pool_k, pool_v, logits)``: the draw happens in the
+        ``(pool_k, pool_v, logits)``; an MoE model's expert counts come
+        last.  The draw happens in the
         process-shared ``sampled_drawer`` with each slot's stream root and
         token count, so slot s's token depends only on its OWN stream and
         logits, never on which other requests share the step — and the
@@ -387,7 +421,7 @@ class ServingEngine:
         materialized and nothing is re-extracted from one, so step cost
         scales with LIVE tokens, not pool capacity.  ``gather_kv`` survives
         only behind ``PagedKVCache.dense_view`` for debugging/oracle use."""
-        logits, new_k, new_v = self.model.decode_paged(
+        logits, new_k, new_v, *counts = self.model.decode_paged(
             params, self.cfg, pool_k, pool_v, tables, tok, pos,
             block_size=self.block_size)
         s = tables.shape[0]
@@ -401,8 +435,8 @@ class ServingEngine:
                                     temperature=self.temperature,
                                     greedy=True, done=done,
                                     pad_id=self.pad_id)
-            return pool_k, pool_v, nxt, lp
-        return pool_k, pool_v, logits
+            return pool_k, pool_v, nxt, lp, *counts
+        return pool_k, pool_v, logits, *counts
 
     # ------------------------------------------------------------------
     # online API
@@ -623,8 +657,8 @@ class ServingEngine:
                 paged_window(self.cfg, tables.shape[1] * self.block_size))
             self.metrics.inc("serve.decode.kv_pages", int((end - first).sum()))
         with tr.span("serve.decode.launch", cat="serve"):
-            out = self._step(
-                params, self.cache.pool_k, self.cache.pool_v,
+            out = self._run(
+                self._step, params, self.cache.pool_k, self.cache.pool_v,
                 jnp.asarray(tables), jnp.asarray(tok),
                 jnp.asarray(pos), jnp.asarray(done))
             if self.greedy:
@@ -782,8 +816,9 @@ class ServingEngine:
                 padded = np.full((pb,), self.pad_id, np.int32)
                 padded[:p] = toks
                 with self._prefill_span(req):
-                    logits, cache = self._prefill(
-                        params, {"tokens": jnp.asarray(padded[None])},
+                    logits, cache = self._run(
+                        self._prefill, params,
+                        {"tokens": jnp.asarray(padded[None])},
                         jnp.int32(p - 1))
                     krows, vrows = cache["k"][:, 0], cache["v"][:, 0]
                     flat = self._write_rows(req.slot, 0, 0, p, pb)
@@ -855,8 +890,8 @@ class ServingEngine:
         chunk = np.full((cb,), self.pad_id, np.int32)
         chunk[:take] = toks[start:start + take]
         with self._prefill_span(req):
-            logits, krows, vrows = self._chunk(
-                params, pool_k, pool_v,
+            logits, krows, vrows = self._run(
+                self._chunk, params, pool_k, pool_v,
                 jnp.asarray(self.sched.tables[req.slot]),
                 jnp.asarray(chunk[None]), jnp.int32(start),
                 jnp.int32(take - 1))
@@ -956,7 +991,7 @@ class ServingEngine:
         # ONE batched prefill for the whole wave — bit-identical numerics to
         # RolloutEngine's prefill; rows are injected into the pool per slot
         # at admission time, so refills never recompile.
-        logits, cache = self._prefill(params, batch)
+        logits, cache = self._run(self._prefill, params, batch, None)
         streams = np.asarray(
             jax.vmap(lambda i: request_stream(key, i))(jnp.arange(b)),
             np.uint32)

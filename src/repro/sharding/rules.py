@@ -95,7 +95,7 @@ def _leaf_spec(path, leaf, cfg: ModelConfig, stage: str, mesh) -> P:
     parent = keys[-2] if len(keys) > 1 else ""
     in_moe = "moe" in keys
     if in_moe:
-        ep = cfg.num_experts % _mdl(mesh) == 0
+        ep = cfg.experts_held[1] % _mdl(mesh) == 0   # expert tables
         table = _TABLE_MOE_EP if ep else _TABLE_MOE_TP
         dims = table.get(name)
     else:

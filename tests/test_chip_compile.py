@@ -6,8 +6,9 @@ described, not attached, and refuses what the chip would refuse (block
 shapes off the (8, 128) tiling, more VMEM than a kernel may use, a kernel
 that autodiff cannot pass) — faults interpret mode never shows.  Widths are
 yi-6b's (d_model 4096, 32 query heads over 4 KV heads of 128, d_ff 11008)
-and, for the MoE grouped matmul, qwen3-moe-30b's (d_model 2048, 128
-experts of d_ff 768).
+and, for the MoE grouped matmul and the MoE serving cell's decode step and
+chunk prefill, qwen3-moe-30b's (d_model 2048, 128 experts of d_ff 768, 16
+held).
 
 The topology is described inside a module-scoped fixture, so importing this
 file loads no TPU library; under several pytest workers only the worker
@@ -117,8 +118,6 @@ def test_decode_layer_scan_reads_pool_in_place(one_chip, monkeypatch):
     neither a per-layer slice (``[..., 131088, 4, 128]``) nor the kernel's
     old page relayout (``[..., 8193, 16, 512]``); the stacked pool only
     passes through the loop to the kernel."""
-    import re
-
     from repro.configs import get_config
     from repro.kernels import ops
     from repro.models.model import build_model
@@ -140,22 +139,7 @@ def test_decode_layer_scan_reads_pool_in_place(one_chip, monkeypatch):
         _shape(one_chip, (slots, 1), jnp.int32),
         _shape(one_chip, (slots,), jnp.int32))
     text = compiled.as_text()
-    bodies = set(re.findall(r"\bwhile\(.*?body=%?([\w.-]+)", text))
-    assert bodies, "no layer scan in the program"
-    comps = re.split(r"\n(?=\S)", text)
-    pool_shape = re.compile(r"=\s*bf16\[(?:\d+,)*(131088,4,128|8193,16,512)\]")
-    seen = 0
-    for comp in comps:
-        name = re.match(r"%?([\w.-]+)", comp)
-        if not name or name.group(1) not in bodies:
-            continue
-        seen += 1
-        for ln in comp.splitlines()[1:]:
-            if pool_shape.search(ln) and "get-tuple-element(" not in ln \
-                    and " parameter(" not in ln:
-                raise AssertionError(f"pool-sized op in the layer scan: "
-                                     f"{ln.strip()[:200]}")
-    assert seen == len(bodies)
+    _assert_not_made_in_scan(text, r"(131088,4,128|8193,16,512)")
     assert "paged_decode_attention" in text
 
 
@@ -178,6 +162,29 @@ def test_swiglu_fwd_and_grad(one_chip):
                   args, (0, 1))
 
 
+def _assert_not_made_in_scan(text, dims):
+    """No op inside a layer scan's while body of the compiled program
+    ``text`` makes a bfloat16 array whose trailing dims match ``dims`` (a
+    regex): such an array only passes through the loop."""
+    import re
+
+    bodies = set(re.findall(r"\bwhile\(.*?body=%?([\w.-]+)", text))
+    assert bodies, "no layer scan in the program"
+    made = re.compile(r"=\s*bf16\[(?:\d+,)*" + dims + r"\]")
+    seen = 0
+    for comp in re.split(r"\n(?=\S)", text):
+        name = re.match(r"%?([\w.-]+)", comp)
+        if not name or name.group(1) not in bodies:
+            continue
+        seen += 1
+        for ln in comp.splitlines()[1:]:
+            if made.search(ln) and "get-tuple-element(" not in ln \
+                    and " parameter(" not in ln:
+                raise AssertionError(f"op in the layer scan makes it: "
+                                     f"{ln.strip()[:200]}")
+    assert seen == len(bodies)
+
+
 def test_rope_fwd_and_grad(one_chip):
     args = (_shape(one_chip, (B, S, H, HD)),
             _shape(one_chip, (B, S, HD // 2), jnp.float32),
@@ -194,6 +201,71 @@ def test_gmm_qwen3_moe_widths(one_chip, d, f):
              _shape(one_chip, (experts * tile, d)),
              _shape(one_chip, (experts, d, f)),
              _shape(one_chip, (experts,), jnp.int32))
+
+
+def _qwen3_moe_cell(one_chip, monkeypatch):
+    """The ``qwen3moe-12l.rollout`` cell's model on the described chip:
+    Qwen3-30B-A3B's widths, 12 layers, 16 of 128 experts held; its
+    parameters' shapes, and ``ops`` steered to the Pallas kernels."""
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models.model import build_model
+
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = get_config("qwen3-moe-30b").replace(num_layers=12, moe_held=(0, 16))
+    model = build_model(cfg)
+    shapes = jax.eval_shape(lambda k: model.init(cfg, k),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: _shape(one_chip, s.shape, s.dtype),
+                          shapes)
+    return cfg, model, params
+
+
+QWEN3_SLOTS, QWEN3_ENTRIES, QWEN3_BLOCK = 256, 64, 16
+# one layer's table of the 16 held experts: the grouped matmul reads it in
+# place from the stacked tables, where a per-layer slice would copy 50 MB
+QWEN3_TABLE = r"(16,2048,768|16,768,2048)"
+
+
+def _qwen3_pool(one_chip):
+    rows = (QWEN3_SLOTS * QWEN3_ENTRIES + 1) * QWEN3_BLOCK
+    return _shape(one_chip, (12, rows, 4, HD))
+
+
+def test_qwen3_moe_decode_step(one_chip, monkeypatch):
+    """The MoE cell's decode step: 256 slots over the 12-layer pool, the
+    router over 128 experts, the dropless expert FFN through the grouped
+    matmul over the 16 held, and its counts returned."""
+    cfg, model, params = _qwen3_moe_cell(one_chip, monkeypatch)
+    pool, slots = _qwen3_pool(one_chip), QWEN3_SLOTS
+    compiled = _compile(
+        lambda p, pk, pv, t, tok, pos: model.decode_paged(
+            p, cfg, pk, pv, t, tok, pos, block_size=QWEN3_BLOCK),
+        params, pool, pool,
+        _shape(one_chip, (slots, QWEN3_ENTRIES), jnp.int32),
+        _shape(one_chip, (slots, 1), jnp.int32),
+        _shape(one_chip, (slots,), jnp.int32))
+    text = compiled.as_text()
+    assert "moe_gmm" in text and "paged_decode_attention" in text
+    _assert_not_made_in_scan(text, QWEN3_TABLE)
+
+
+@pytest.mark.parametrize("chunk", [16, 512])
+def test_qwen3_moe_chunk_prefill(one_chip, monkeypatch, chunk):
+    """The MoE cell's continuation prefill of one chunk against the pool, at
+    the shortest tail bucket and the longest prompt bucket."""
+    cfg, model, params = _qwen3_moe_cell(one_chip, monkeypatch)
+    pool = _qwen3_pool(one_chip)
+    compiled = _compile(
+        lambda p, pk, pv, t, tok, st, last: model.prefill_paged(
+            p, cfg, pk, pv, t, tok, st, block_size=QWEN3_BLOCK, last=last),
+        params, pool, pool, _shape(one_chip, (QWEN3_ENTRIES,), jnp.int32),
+        _shape(one_chip, (1, chunk), jnp.int32),
+        _shape(one_chip, (), jnp.int32), _shape(one_chip, (), jnp.int32))
+    text = compiled.as_text()
+    assert "moe_gmm" in text
+    _assert_not_made_in_scan(text, QWEN3_TABLE)
 
 
 def test_sharded_train_step_runs_kernels_per_shard(topo, monkeypatch):

@@ -95,6 +95,34 @@ def test_gmm(t, d, f, e, tile, rng):
                                rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("stacked", [False, True], ids=["table", "stack"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_gmm_rows_past_the_groups(dtype, stacked, rng):
+    """A buffer sized for the worst case, filled only in part (the serving
+    path's expert FFN): the rows of the groups match the oracle; the tiles
+    past them are not computed (zero on the jnp path).  ``stack``: the
+    weights are layer 1 of a 3-layer stack, read in place."""
+    t, d, f, e, tile = 256, 32, 256, 4, 16
+    sizes = jnp.asarray([32, 0, 16, 48], jnp.int32)       # 6 of 16 tiles
+    x = jax.random.normal(rng, (t, d)).astype(dtype)
+    ws = jax.random.normal(jax.random.fold_in(rng, 1),
+                           (3, e, d, f)).astype(dtype)
+    w, layer = (ws, 1) if stacked else (ws[1], 0)
+    live = int(jnp.sum(sizes))
+    want = ref.gmm(x[:live], ws[1], sizes)
+    tol = 1e-4 if dtype == "float32" else 2e-2
+    out = gmm.gmm(x, w, sizes, layer, tile_t=tile, block_f=128,
+                  interpret=True)
+    np.testing.assert_allclose(np.asarray(out[:live], np.float32),
+                               np.asarray(want, np.float32), rtol=tol,
+                               atol=tol)
+    cpu = ops.gmm(x, w, sizes, layer, tile_t=tile)
+    np.testing.assert_allclose(np.asarray(cpu[:live], np.float32),
+                               np.asarray(want, np.float32), rtol=tol,
+                               atol=tol)
+    assert not np.any(np.asarray(cpu[live:], np.float32))
+
+
 def test_ops_attention_grads_match_naive(rng):
     """custom-VJP flash backward == autodiff through the naive oracle."""
     q = jax.random.normal(rng, (2, 32, 8, 16))

@@ -413,21 +413,15 @@ def test_enabled_spans_reach_the_profiler_trace(setup, tmp_path):
         assert min(abs(at - s) for s in starts[e["name"]]) < 1e6
 
 
-@pytest.mark.parametrize("greedy", [True, False])
-def test_launch_and_host_read_counts(setup, greedy):
-    """Exact ``serve.launches`` / ``serve.host_reads`` of a run of known
-    shape: n distinct prompts admitted in the first step, every request
-    running to ``max_new`` (the EOS id lies outside the vocabulary).  Per
-    submit (sampled): the stream, 1 launch + 1 read; per admission: the
-    prefill, two pool writes and the first-token draw, 4 launches + 2
-    reads; per decode step: ``_step`` (+ ``_draw`` sampled) and 2 reads."""
-    cfg, _, params = setup
+def _check_launch_and_host_read_counts(cfg, params, greedy):
     n, mn = 3, 5
     eng = ServingEngine(cfg, max_new=mn, eos_id=cfg.vocab_size + 7,
                         pad_id=TOK.pad_id, greedy=greedy, max_slots=n,
                         block_size=4)
     for p in _prompts(n, 8, seed=9):
         eng.submit(p)
+    eng.step(params)
+    eng.metrics.snapshot()        # a snapshot mid-run reads no counted value
     outs = eng.drain(params)
     assert sorted(len(o.gen) for o in outs) == [mn] * n
     st = eng.stats()
@@ -437,6 +431,34 @@ def test_launch_and_host_read_counts(setup, greedy):
     per_step = 1 if greedy else 2
     assert st["launches"] == n * (per_submit + 4) + steps * per_step
     assert st["host_reads"] == n * (per_submit + 2) + steps * 2
+    return eng
+
+
+@pytest.mark.parametrize("greedy", [True, False])
+def test_launch_and_host_read_counts(setup, greedy):
+    """Exact ``serve.launches`` / ``serve.host_reads`` of a run of known
+    shape: n distinct prompts admitted in the first step, every request
+    running to ``max_new`` (the EOS id lies outside the vocabulary).  Per
+    submit (sampled): the stream, 1 launch + 1 read; per admission: the
+    prefill, two pool writes and the first-token draw, 4 launches + 2
+    reads; per decode step: ``_step`` (+ ``_draw`` sampled) and 2 reads."""
+    cfg, _, params = setup
+    _check_launch_and_host_read_counts(cfg, params, greedy)
+
+
+@pytest.mark.parametrize("greedy", [True, False])
+def test_moe_counts_add_no_launch_or_host_read(greedy):
+    """An MoE engine, holding 2 of its 4 experts, launches and reads as
+    the dense one does: its ``serve.moe.*`` counts ride the programs it
+    runs anyway and are read by ``snapshot()`` alone.  Each of the 3
+    admissions and 4 decode steps applies the 2 expert layers once."""
+    cfg = get_smoke_config("qwen3-moe-30b").replace(
+        dtype="float32", remat=False, moe_held=(2, 2))
+    params = build_model(cfg).init(cfg, jax.random.PRNGKey(0))
+    eng = _check_launch_and_host_read_counts(cfg, params, greedy)
+    c = eng.metrics.snapshot()["counters"]
+    assert c["serve.moe.calls"] == 2 * (3 + 4)
+    assert 0 < c["serve.moe.rows"] <= c["serve.moe.padded_rows"]
 
 
 # ---------------------------------------------------------------------------
